@@ -21,6 +21,7 @@ import sys
 import traceback
 
 from benchmarks.common import BENCH, Csv
+from repro.jax_cache import use_compile_cache
 
 MODULES = [
     ("fig1+3", "benchmarks.fig_overheads"),
@@ -48,6 +49,7 @@ def main() -> int:
                     help="write a Chrome trace-event JSON (Perfetto-"
                          "loadable) from one traced benchmark run")
     args = ap.parse_args()
+    use_compile_cache()
     if args.trace_out:
         import benchmarks.common
         benchmarks.common.TRACE_OUT = args.trace_out

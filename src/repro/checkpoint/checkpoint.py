@@ -31,9 +31,7 @@ _SEP = "§"
 
 
 def _flatten(tree) -> Dict[str, Any]:
-    # jax.tree.flatten_with_path only exists in newer jax; use the stable
-    # tree_util spelling so the pinned toolchain works.
-    flat = jax.tree_util.tree_flatten_with_path(tree)[0]
+    flat = jax.tree.flatten_with_path(tree)[0]
     out = {}
     for path, leaf in flat:
         key = _SEP.join(str(getattr(k, "key", getattr(k, "idx", k)))

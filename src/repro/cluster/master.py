@@ -81,8 +81,9 @@ from repro.cluster.obs import MetricsRegistry, Tracer
 from repro.cluster.shm import SegmentPool, shm_prefix
 from repro.cluster.transport import (InProcTransport, SocketTransport,
                                      Transport)
-from repro.cluster.worker import (ChunkDone, ChunkTask, ComputeFn, Worker,
-                                  WorkerDone, WorkerFailed, WorkerRejoined,
+from repro.cluster.worker import (ChunkDone, ChunkTask, ComputeFn,
+                                  KernelBackend, Worker, WorkerDone,
+                                  WorkerFailed, WorkerRejoined,
                                   numpy_backend, rhs_width, shard_digest)
 from repro.core.coding import MDSCode
 from repro.core.predictor import SpeedPredictor
@@ -293,6 +294,14 @@ class CodedExecutionEngine:
         # logic is identical either way
         self.transport: Transport = (transport if transport is not None
                                      else InProcTransport())
+        if (isinstance(self.transport, SocketTransport)
+                and isinstance(compute, KernelBackend)):
+            # each child process would build its own backend and reach for
+            # the accelerator this process already holds
+            raise ValueError(
+                "KernelBackend computes in the process that holds the "
+                "accelerator; run it on the in-process transport (worker "
+                "processes of a SocketTransport keep the NumPy backend)")
         # observability plane: pass a Tracer to capture the chunk lifecycle
         # (or toggle engine.tracer.enable() later — the default tracer is
         # created disabled, so an untraced engine pays one attribute check

@@ -1556,9 +1556,8 @@ class SocketTransport:
         if not self.adopt:
             # children get the UNWRAPPED injector (the engine's
             # TracedInjector holds the master's tracer and a lock) and
-            # re-wrap with their own process-local tracer; the compute
-            # backend ships as a spec string for the known unpicklable
-            # backends
+            # re-wrap with their own process-local tracer; the NumPy
+            # compute backend ships as a spec string
             base_injector = getattr(injector, "inner", injector)
             spec = _compute_spec(compute)
             ctx = mp.get_context(self.mp_method)
@@ -1912,18 +1911,12 @@ def _compute_spec(compute):
     """Picklable description of the compute backend for the children."""
     if compute is numpy_backend:
         return "numpy"
-    if type(compute).__name__ == "KernelBackend":
-        # jax handles and locks do not pickle; each child builds its own
-        return "kernel"
     return compute                  # must be picklable (module-level fn)
 
 
 def _resolve_compute(spec):
     if spec == "numpy":
         return numpy_backend
-    if spec == "kernel":
-        from repro.cluster.worker import kernel_backend
-        return kernel_backend()
     return spec
 
 

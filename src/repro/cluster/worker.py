@@ -40,7 +40,7 @@ The compute backend is pluggable: the default is plain BLAS
 (``a[rows] @ x`` — a BLAS-2 matvec for a 1-D operand, one BLAS-3 GEMM for
 an ``(d, B)`` multi-RHS block); :class:`KernelBackend` (via
 :func:`kernel_backend`) routes each chunk through the Pallas
-``coded_matvec`` kernel (interpret mode off-TPU) — same semantics,
+``coded_matvec`` kernel (interpret mode on the CPU backend) — same semantics,
 exercised by the demo to prove the engine drives ``repro.kernels``.  A
 backend may additionally implement the shard-aware protocol
 (``compute_chunk(worker_id, shard_id, shard, r0, r1, x)`` plus optional
@@ -185,8 +185,8 @@ def numpy_backend(a_rows: np.ndarray, x: np.ndarray) -> np.ndarray:
     return a_rows @ x
 
 
-def _next_pow2(x: int, floor: int = 8) -> int:
-    v = floor
+def _next_pow2(x: int) -> int:
+    v = 1
     while v < x:
         v *= 2
     return v
@@ -208,11 +208,17 @@ class KernelBackend:
       once; small operands are content-keyed, large immutable blocks are
       identity-keyed (content-keying an ``(d, B)`` block would cost
       O(d·B) per chunk);
-    * chunk row counts are bucketed to the next power of two (floor 8), and
-      multi-RHS widths to the next power of two (floor 1), so
-      heterogeneous tenants and coalesced batch widths land on a handful
-      of kernel shapes instead of retracing the jit for every distinct
-      ``(rows_per_chunk, B)``.
+    * each chunk is one ``ops.chunk_matvec`` call on the resident shard:
+      the chunk's start row is a traced argument, so one compiled program
+      per ``(shard shape, rows_per_chunk, B)`` serves every chunk, and the
+      chunk is walked in row blocks small enough for the kernel's VMEM
+      whatever its size.  Multi-RHS widths are bucketed to the next power
+      of two (floor 1), so coalesced batch widths land on a handful of
+      kernel shapes instead of retracing for every distinct ``B``.
+
+    It computes in the process that holds the accelerator: the engine
+    refuses it with a multi-process transport, whose children would each
+    reach for the same chip.
 
     One instance is shared by all workers of ONE engine (shard ids are
     engine-scoped — do not share a backend between engines); cache
@@ -226,14 +232,11 @@ class KernelBackend:
     _X_CACHE_CAP = 16
     _X_HASH_CAP = 64 * 1024        # max bytes content-keyed per lookup
 
-    def __init__(self, interpret: Optional[bool] = None,
-                 row_bucket_floor: int = 8):
+    def __init__(self):
         import jax.numpy as jnp           # deferred: jax is heavyweight
         from repro.kernels import ops
         self._jnp = jnp
         self._ops = ops
-        self.interpret = interpret
-        self.row_bucket_floor = row_bucket_floor
         self._lock = threading.Lock()
         # guarded_by: _lock
         self._shards: "OrderedDict[Tuple[int, str], object]" = OrderedDict()
@@ -326,27 +329,18 @@ class KernelBackend:
 
     def compute_chunk(self, worker_id: int, shard_id: str, shard: np.ndarray,
                       r0: int, r1: int, x: np.ndarray) -> np.ndarray:
-        jnp, ops = self._jnp, self._ops
         dev = self._device_shard(worker_id, shard_id, shard)
-        rows = r1 - r0
-        bucket = _next_pow2(rows, self.row_bucket_floor)
-        a_rows = dev[r0:r1]
-        if bucket != rows:
-            a_rows = jnp.pad(a_rows, ((0, bucket - rows), (0, 0)))
-        ids = jnp.zeros((1,), jnp.int32)
         if x.ndim == 1:
-            out = ops.coded_matvec(a_rows, self._device_x(x), ids, bucket,
-                                   interpret=self.interpret)
-            return np.asarray(out[0][:rows], dtype=np.float64)
+            out = self._ops.chunk_matvec(dev, self._device_x(x), r0, r1 - r0)
+            return np.asarray(out, dtype=np.float64)
         # multi-RHS chunk: bucket the batch width to the next power of two
         # (floor 1) so coalesced rounds of heterogeneous widths land on a
         # few traced shapes; zero columns cost nothing and are sliced off
         b = x.shape[1]
-        b_bucket = _next_pow2(b, 1)
+        b_bucket = _next_pow2(b)
         xd = self._device_x(x, pad_cols=b_bucket - b)
-        out = ops.coded_matvec(a_rows, xd, ids, bucket,
-                               interpret=self.interpret)
-        return np.asarray(out[0][:rows, :b], dtype=np.float64)
+        out = self._ops.chunk_matvec(dev, xd, r0, r1 - r0)
+        return np.asarray(out[:, :b], dtype=np.float64)
 
     def drop_shard(self, worker_id: int, shard_id: str) -> None:
         with self._lock:
@@ -357,21 +351,14 @@ class KernelBackend:
             return {"shards": len(self._shards),
                     "x_entries": len(self._x_cache),
                     "x_hits": self._x_hits,
-                    "x_misses": self._x_misses}
-
-    # -- plain ComputeFn fallback ------------------------------------------
-    def __call__(self, a_rows: np.ndarray, x: np.ndarray) -> np.ndarray:
-        jnp, ops = self._jnp, self._ops
-        ids = jnp.zeros((1,), jnp.int32)
-        out = ops.coded_matvec(jnp.asarray(a_rows, jnp.float32),
-                               jnp.asarray(x, jnp.float32), ids,
-                               a_rows.shape[0], interpret=self.interpret)
-        return np.asarray(out[0], dtype=np.float64)
+                    "x_misses": self._x_misses,
+                    "shard_bytes": sum(int(d.nbytes)
+                                       for d in self._shards.values())}
 
 
-def kernel_backend(interpret: Optional[bool] = None) -> KernelBackend:
+def kernel_backend() -> KernelBackend:
     """Chunk compute through the Pallas coded_matvec kernel (cached)."""
-    return KernelBackend(interpret=interpret)
+    return KernelBackend()
 
 
 class _TaskProgress:

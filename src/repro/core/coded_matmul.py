@@ -31,11 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.5 exposes shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - depends on pinned jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.core.coding import MDSCode
 from repro.core.s2c2 import Allocation
 
@@ -65,7 +60,9 @@ def masked_partial_products(coded: jax.Array, x: jax.Array, begin: jax.Array,
     rows, d = coded.shape
     rpc = rows // chunks
     mask = _chunk_mask(begin, count, chunks)               # (chunks,)
-    y = (coded.reshape(chunks, rpc, d) @ x).reshape(chunks, rpc)
+    # HIGHEST: on TPU the default f32 matmul rounds operands to bf16
+    y = jnp.matmul(coded.reshape(chunks, rpc, d), x,
+                   precision=jax.lax.Precision.HIGHEST).reshape(chunks, rpc)
     return y * mask[:, None].astype(y.dtype)
 
 
@@ -148,7 +145,7 @@ class CodedMatvec:
             return jax.lax.psum(contrib, axis)    # (chunks, k, rpc), replicated
 
         rows = coded.shape[1]
-        dec = _shard_map(
+        dec = jax.shard_map(
             worker, mesh=self.mesh,
             in_specs=(P(self.axis, None, None), P(), P(), P(), P()),
             out_specs=P(),

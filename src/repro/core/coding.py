@@ -130,7 +130,8 @@ def encode_blocks(g: jax.Array, blocks: jax.Array) -> jax.Array:
 
     g: (n, k); blocks: (k, rows, ...) -> (n, rows, ...)
     """
-    return jnp.tensordot(g.astype(blocks.dtype), blocks, axes=([1], [0]))
+    return jnp.tensordot(g.astype(blocks.dtype), blocks, axes=([1], [0]),
+                         precision=jax.lax.Precision.HIGHEST)
 
 
 def encode_matrix(g: jax.Array, a: jax.Array, k: int) -> jax.Array:

@@ -42,7 +42,12 @@ def _kernel(ids_ref, a_ref, x_ref, o_ref, acc_ref, *, n_dtiles: int):
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(a_ref[...], x_ref[...],
+    # f32 operands get full-f32 MXU passes (the TPU default rounds them to
+    # bf16, which misses a 1e-3 relative error bound at d = 8192); Mosaic
+    # accepts that contract precision for f32 operands only
+    f32 = a_ref.dtype == jnp.float32 and x_ref.dtype == jnp.float32
+    precision = jax.lax.Precision.HIGHEST if f32 else None
+    acc_ref[...] += jnp.dot(a_ref[...], x_ref[...], precision=precision,
                             preferred_element_type=jnp.float32)
 
     @pl.when(j == n_dtiles - 1)

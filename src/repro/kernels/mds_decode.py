@@ -23,7 +23,8 @@ def _kernel(w_ref, y_ref, o_ref):
     """w_ref: (1, k, m); y_ref: (1, m, tr); o_ref: (1, k, tr)."""
     w = w_ref[0, :, :].astype(jnp.float32)
     y = y_ref[0, :, :].astype(jnp.float32)
-    o_ref[0, :, :] = jnp.dot(w, y, preferred_element_type=jnp.float32
+    o_ref[0, :, :] = jnp.dot(w, y, precision=jax.lax.Precision.HIGHEST,
+                             preferred_element_type=jnp.float32
                              ).astype(o_ref.dtype)
 
 

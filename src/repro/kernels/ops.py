@@ -1,13 +1,16 @@
 """Public jit'd wrappers for the Pallas kernels.
 
 Each op dispatches to the Pallas kernel on TPU (or in interpret mode on
-CPU, which executes the kernel body in Python — used by tests/CI) and pads
+CPU, which executes the kernel body in Python — used by tests/CI; any other
+backend is refused rather than silently interpreted) and pads
 inputs to TPU tile alignment (8 sublanes × 128 lanes for f32; the wrappers
 round up to multiples that work for all supported dtypes).  The pure-jnp
 oracles live in ref.py.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -18,13 +21,28 @@ from repro.kernels.lstm_cell import lstm_cell_pallas
 from repro.kernels.mds_decode import mds_decode_pallas
 from repro.kernels.mds_encode import mds_encode_pallas
 
-__all__ = ["coded_matvec", "mds_encode", "mds_decode", "lstm_cell",
-           "interpret_default"]
+__all__ = ["coded_matvec", "chunk_matvec", "mds_encode", "mds_decode",
+           "lstm_cell", "interpret_default", "MAX_BLOCK_ROWS"]
+
+#: Largest row block ``chunk_matvec`` hands the kernel.  A (1024, 512) f32
+#: tile double-buffered plus its accumulator stays well inside the 16 MiB
+#: scoped VMEM of a v5e core; a 4096-row block does not.
+MAX_BLOCK_ROWS = 1024
 
 
 def interpret_default() -> bool:
-    """Pallas runs natively only on TPU; everywhere else use interpret mode."""
-    return jax.default_backend() != "tpu"
+    """Native Pallas on TPU, interpret mode on CPU (tests), else an error.
+
+    A GPU or any other backend would otherwise run the kernels through the
+    Python interpreter and report a device it never used.
+    """
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels run natively on TPU and in interpret "
+                       f"mode on CPU only; the default backend is {backend!r}")
 
 
 def _round_up(x: int, m: int) -> int:
@@ -59,6 +77,27 @@ def coded_matvec(a: jax.Array, x: jax.Array, block_ids: jax.Array,
                               d_tile=d_tile, interpret=interpret)
     out = out[:, :, :nvec]
     return out[:, :, 0] if squeeze else out
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "interpret"))
+def chunk_matvec(shard: jax.Array, x: jax.Array, r0: jax.Array, rows: int,
+                 interpret: bool | None = None) -> jax.Array:
+    """``shard[r0:r0+rows] @ x`` for one engine chunk, in one kernel call.
+
+    The chunk is walked as blocks of at most :data:`MAX_BLOCK_ROWS` rows
+    through the kernel's block-id table (rows padded to a whole number of
+    blocks), so any chunk size fits the kernel's VMEM.  ``r0`` is traced:
+    one compiled program serves every chunk of a shard; ``r0 + rows`` must
+    not exceed the shard's rows.  Returns ``(rows,)`` for a vector ``x``,
+    else ``(rows, nvec)``.
+    """
+    block = min(_round_up(rows, 8), MAX_BLOCK_ROWS)
+    padded = _round_up(rows, block)
+    a = jax.lax.dynamic_slice_in_dim(shard, r0, rows)
+    a = jnp.pad(a, ((0, padded - rows), (0, 0)))
+    ids = jnp.arange(padded // block, dtype=jnp.int32)
+    out = coded_matvec(a, x, ids, block, interpret=interpret)
+    return out.reshape((padded,) + out.shape[2:])[:rows]
 
 
 # ---------------------------------------------------------------------------

@@ -58,7 +58,7 @@ def dryrun_cell(arch_id: str, shape_name: str, mesh_name: str,
         rules = serve_rules(cfg, tp=mesh.shape["model"]) or None
     t0 = time.time()
 
-    with mesh:
+    with jax.set_mesh(mesh):
         batch_abs = abstract_inputs(cfg, shape)
         batch_sh = input_shardings(cfg, shape, mesh, rules)
 

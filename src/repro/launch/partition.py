@@ -9,7 +9,7 @@ from __future__ import annotations
 from typing import Dict, Optional, Sequence
 
 import jax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AbstractMesh, Mesh, PartitionSpec as P
 
 __all__ = ["DEFAULT_RULES", "resolve_axes", "current_mesh", "constrain",
            "mentions"]
@@ -85,14 +85,13 @@ def mentions(spec: P, axis: str) -> bool:
     return False
 
 
-def current_mesh() -> Optional[Mesh]:
-    """The ambient `with mesh:` context, or None (e.g. CPU smoke tests)."""
-    try:
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-        return m if m.shape else None
-    except Exception:
-        return None
+def current_mesh() -> Optional[AbstractMesh]:
+    """The ambient ``jax.set_mesh`` mesh, or None (e.g. CPU smoke tests).
+
+    Abstract, so it is readable inside ``jit`` as well as outside.
+    """
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def constrain(x, axes: Sequence[Optional[str]], rules: Optional[Dict] = None):

@@ -72,6 +72,37 @@ class TestCodedMatvec:
                                    rtol=3e-4, atol=3e-4)
 
 
+class TestChunkMatvec:
+    """One engine chunk ``shard[r0:r0+rows] @ x``, walked in row blocks."""
+
+    @pytest.mark.parametrize("shard_rows,r0,rows,nvec", [
+        (64, 8, 40, None),                        # one block, padded to 40
+        (96, 13, 5, 3),                           # unaligned start, tiny
+        (2 * ops.MAX_BLOCK_ROWS + 300, 150,       # three blocks, the last
+         2 * ops.MAX_BLOCK_ROWS + 100, 2),        # one mostly padding
+    ])
+    def test_matches_slice_product(self, shard_rows, r0, rows, nvec):
+        d = 128
+        shard = _rand((shard_rows, d), jnp.float32)
+        x = _rand((d,) if nvec is None else (d, nvec), jnp.float32)
+        got = ops.chunk_matvec(shard, x, r0, rows)
+        want = np.asarray(shard, np.float64)[r0:r0 + rows] @ np.asarray(
+            x, np.float64)
+        assert got.shape == want.shape
+        np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4,
+                                   atol=2e-4)
+
+    def test_start_row_is_traced(self):
+        """Every chunk of a shard reuses one compiled program."""
+        shard = _rand((80, 128), jnp.float32)
+        x = _rand((128,), jnp.float32)
+        ops.chunk_matvec(shard, x, 0, 16)
+        before = ops.chunk_matvec._cache_size()
+        for r0 in (16, 32, 48, 64):
+            ops.chunk_matvec(shard, x, r0, 16)
+        assert ops.chunk_matvec._cache_size() == before
+
+
 class TestMDSEncode:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("n,k,rows,d", [

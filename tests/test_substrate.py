@@ -151,8 +151,8 @@ class TestPredictor:
 
 class TestShardingRules:
     def _mesh(self):
-        from repro.launch.mesh import _AXIS_KW
-        return jax.make_mesh((1, 1), ("data", "model"), **_AXIS_KW(2))
+        from repro.launch.mesh import auto_axes
+        return jax.make_mesh((1, 1), ("data", "model"), **auto_axes(2))
 
     def test_nondivisible_drops(self):
         from repro.launch.mesh import make_worker_mesh
