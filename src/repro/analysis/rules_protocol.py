@@ -2,8 +2,11 @@
 
 S2C204 enforces the PR-6 overhead contract: with tracing off, a call
 site costs exactly one attribute read — so every ``<tracer>.emit(...)``
-outside ``obs.py`` must be lexically dominated by an
-``if <tracer>.enabled:`` test.  The hot-loop alias form
+outside the ``Tracer`` sink itself (the methods of ``class Tracer`` in
+``obs.py``) must be lexically dominated by an ``if <tracer>.enabled:``
+test.  That includes ``obs.py``'s own helpers: the span helper emits into
+a tracer, and its emission is checked like any call site's.  The
+hot-loop alias form
 
     if self.tracer.enabled:
         emit = self.tracer.emit
@@ -73,15 +76,17 @@ class TracerGuardRule:
     rule_id = "S2C204"
     name = "tracer-guard"
 
-    EXEMPT_BASENAMES = {"obs.py"}
+    #: (module basename, class) whose methods are the tracer sink itself
+    EXEMPT_CLASSES = {("obs.py", "Tracer")}
 
     def run(self, project: Project) -> List[Finding]:
         findings: List[Finding] = []
         for src in project.files:
             base = src.path.rsplit("/", 1)[-1]
-            if base in self.EXEMPT_BASENAMES:
-                continue
-            for _cls, fn in iter_functions(src):
+            for cls, fn in iter_functions(src):
+                if cls is not None and \
+                        (base, cls.name) in self.EXEMPT_CLASSES:
+                    continue
                 findings.extend(self._check_function(src, fn))
         return findings
 

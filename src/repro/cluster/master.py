@@ -224,6 +224,9 @@ class _RoundState:
         # guarded_by: thread:round-driver
         self.outstanding: List[Set[int]] = [set() for _ in range(n)]
         self.chunks_done = np.zeros(n, dtype=np.int64)
+        # Σ ChunkDone.cost of the chunk results received (obs.add_cost)
+        # guarded_by: thread:round-driver
+        self.cost = list(obs.NO_COST)
         self.wasted_chunks = np.zeros(n, dtype=np.int64)
         self.finish_t = np.full(n, np.inf)      # WorkerDone wall time
         self.last_event_t = np.full(n, np.nan)
@@ -421,6 +424,22 @@ class CodedExecutionEngine:
         self._m_batched = reg.counter(
             "s2c2_batched_rounds_total", "rounds executed with RHS "
             "width > 1")
+        # host cost of the rounds (RoundMetrics.chunk_* / host_cpu_s)
+        self._m_backend_calls = reg.counter(
+            "s2c2_backend_calls_total",
+            "backend chunk calls measured by the round drivers")
+        self._m_backend_wall = reg.counter(
+            "s2c2_backend_wall_seconds_total",
+            "wall seconds of the measured backend chunk calls")
+        self._m_backend_cpu_calls = reg.counter(
+            "s2c2_backend_cpu_calls_total",
+            "measured backend chunk calls whose thread CPU was read")
+        self._m_backend_cpu = reg.counter(
+            "s2c2_backend_cpu_seconds_total",
+            "calling-thread CPU seconds of those calls")
+        self._m_driver_cpu = reg.counter(
+            "s2c2_round_driver_cpu_seconds_total",
+            "round drivers' own thread CPU seconds, plan to decode")
         # partition/recovery plane
         self._m_partition_credits = reg.counter(
             "s2c2_partition_credits_total",
@@ -479,6 +498,12 @@ class CodedExecutionEngine:
             self._m_failures.inc(len(m.worker_failures))
         if m.rhs_width > 1:
             self._m_batched.inc()
+        if m.chunk_calls:
+            self._m_backend_calls.inc(m.chunk_calls)
+            self._m_backend_wall.inc(m.chunk_wall_s)
+            self._m_backend_cpu_calls.inc(m.chunk_cpu_calls)
+            self._m_backend_cpu.inc(m.chunk_cpu_s)
+        self._m_driver_cpu.inc(m.host_cpu_s)
         if chunk_counts is not None:
             for w, c in enumerate(chunk_counts):
                 if c > 0:
@@ -1085,333 +1110,332 @@ class CodedExecutionEngine:
         # the workers stretch B-wide chunks to B× the virtual time, so the
         # deadline clock, measured speeds, and row accounting must follow
         work_per_chunk = rpc * width * cfg.row_cost
-        t_plan0 = time.perf_counter()
-        alloc, planned = self._plan(data, strategy, width)
-        slack = getattr(strategy, "timeout_slack", cfg.timeout_slack)
-        # snapshot the injector step under the observation lock (concurrent
-        # round drivers bump it in _observe): every dispatch this round —
-        # including §4.3 waves and steals — must see one consistent value
-        with self._obs_lock:
-            iteration = self.iteration
+        cpu0 = time.thread_time()
+        with obs.span("s2c2.round.plan", self.tracer, obs.KIND_ROUND_PLAN,
+                      round_id=rid, strategy=type(strategy).__name__):
+            alloc, planned = self._plan(data, strategy, width)
+            slack = getattr(strategy, "timeout_slack", cfg.timeout_slack)
+            # snapshot the injector step under the observation lock (concurrent
+            # round drivers bump it in _observe): every dispatch this round —
+            # including §4.3 waves and steals — must see one consistent value
+            with self._obs_lock:
+                iteration = self.iteration
 
-        state = _RoundState(n, k, C)
-        if seed_acks:
-            # recovery: journaled chunk credits become coverage BEFORE any
-            # dispatch — these chunks are never recomputed
-            for c, entries in sorted(seed_acks.items()):
-                for w_, res in entries:
-                    if len(state.used[c]) >= k or w_ in state.covered_by[c]:
+            state = _RoundState(n, k, C)
+            if seed_acks:
+                # recovery: journaled chunk credits become coverage BEFORE any
+                # dispatch — these chunks are never recomputed
+                for c, entries in sorted(seed_acks.items()):
+                    for w_, res in entries:
+                        if len(state.used[c]) >= k or w_ in state.covered_by[c]:
+                            continue
+                        state.covered_by[c].add(w_)
+                        state.used[c].append(w_)
+                        state.partials[(w_, c)] = res
+                        state.need -= 1
+                        state.recovered_chunks += 1
+                    if len(state.used[c]) >= k:
+                        state.pending.discard(c)
+                if state.recovered_chunks:
+                    self._m_recovered_chunks.labels(
+                        transport=self._transport_kind).inc(
+                            state.recovered_chunks)
+                    if self.tracer.enabled:
+                        self.tracer.emit(obs.KIND_ROUND_RESUME, round_id=rid,
+                                         recovered=state.recovered_chunks,
+                                         need=state.need)
+                    logger.info("round %d resumed from journal: %d chunk "
+                                "credit(s) seeded, need=%d", rid,
+                                state.recovered_chunks, state.need)
+        with obs.span("s2c2.round.dispatch", self.tracer,
+                      obs.KIND_ROUND_DISPATCH, round_id=rid) as dispatch:
+            fenced: List[int] = []
+            for w in range(n):
+                if alloc.count[w] > 0:
+                    ids = [int((alloc.begin[w] + j) % C)
+                           for j in range(int(alloc.count[w]))]
+                    # a resumed round dispatches only what the journal floor
+                    # does not already cover (no-op without seeded coverage)
+                    ids = [c for c in ids if len(state.used[c]) < k
+                           and w not in state.covered_by[c]]
+                    if not ids:
                         continue
-                    state.covered_by[c].add(w_)
-                    state.used[c].append(w_)
-                    state.partials[(w_, c)] = res
-                    state.need -= 1
-                    state.recovered_chunks += 1
-                if len(state.used[c]) >= k:
-                    state.pending.discard(c)
-            if state.recovered_chunks:
-                self._m_recovered_chunks.labels(
-                    transport=self._transport_kind).inc(
-                        state.recovered_chunks)
-                if self.tracer.enabled:
-                    self.tracer.emit(obs.KIND_ROUND_RESUME, round_id=rid,
-                                     recovered=state.recovered_chunks,
-                                     need=state.need)
-                logger.info("round %d resumed from journal: %d chunk "
-                            "credit(s) seeded, need=%d", rid,
-                            state.recovered_chunks, state.need)
-        t0 = time.perf_counter()
-        fenced: List[int] = []
-        for w in range(n):
-            if alloc.count[w] > 0:
-                ids = [int((alloc.begin[w] + j) % C)
-                       for j in range(int(alloc.count[w]))]
-                # a resumed round dispatches only what the journal floor
-                # does not already cover (no-op without seeded coverage)
-                ids = [c for c in ids if len(state.used[c]) < k
-                       and w not in state.covered_by[c]]
-                if not ids:
-                    continue
-                if w in self.dead:
-                    # the planner can still allocate to a CONFIRMED-dead
-                    # worker (its verdict raced this round's plan):
-                    # dispatching into the black hole would strand those
-                    # coverage slots until starvation, so divert them.
-                    # Only the engine-level fence counts here — a worker
-                    # whose private dead flag is set but that the §4.4
-                    # detector has not yet confirmed must still receive
-                    # its allocation, because its SILENCE on dispatched
-                    # work is exactly the evidence the detector needs.
-                    state.cancelled.add(w)
-                    fenced.extend(ids)
-                    continue
-                self._dispatch(state, rid, iteration, data, x, w, ids)
-        if fenced:
-            state.orphans |= self._failover_dispatch(
-                state, rid, iteration, data, x, -1, sorted(set(fenced)))
-        t_disp = time.perf_counter()
+                    if w in self.dead:
+                        # the planner can still allocate to a CONFIRMED-dead
+                        # worker (its verdict raced this round's plan):
+                        # dispatching into the black hole would strand those
+                        # coverage slots until starvation, so divert them.
+                        # Only the engine-level fence counts here — a worker
+                        # whose private dead flag is set but that the §4.4
+                        # detector has not yet confirmed must still receive
+                        # its allocation, because its SILENCE on dispatched
+                        # work is exactly the evidence the detector needs.
+                        state.cancelled.add(w)
+                        fenced.extend(ids)
+                        continue
+                    self._dispatch(state, rid, iteration, data, x, w, ids)
+            if fenced:
+                state.orphans |= self._failover_dispatch(
+                    state, rid, iteration, data, x, -1, sorted(set(fenced)))
+        t0 = dispatch.t0
 
-        active = {w for w in range(n) if alloc.count[w] > 0}
-        # MDSCoded is the conventional baseline: pure any-k collection, no
-        # §4.3 reassignment (that is exactly what S²C² adds on top of it) —
-        # its allowance is only a generous liveness bound.
-        use_timeout = isinstance(strategy, (BasicS2C2, GeneralS2C2))
-        factor = 1.0 + slack if use_timeout else 20.0
-        # §4.3 under pipelining: the timeout clock runs on each worker's
-        # SERVICE time (from when it began the task — workers stamp
-        # ``t_start`` into their events), not from dispatch.  A task still
-        # queued behind other rounds' work gets a dispatch-anchored
-        # allowance stretched by the live backlog instead.  At inflight=1
-        # start ≈ dispatch and this reduces exactly to the paper's rule.
-        window = max(planned, 1e-3)     # per-worker virtual-time allowance
-        window_frozen = False           # set by k-finisher arming / waves
-        floor_deadline = 0.0            # explicit extensions (no-target case)
-        waves = 0
-        mispredicted = False
+        with obs.span("s2c2.round.collect", self.tracer,
+                      obs.KIND_ROUND_COLLECT, round_id=rid) as collect:
+            active = {w for w in range(n) if alloc.count[w] > 0}
+            # MDSCoded is the conventional baseline: pure any-k collection, no
+            # §4.3 reassignment (that is exactly what S²C² adds on top of it) —
+            # its allowance is only a generous liveness bound.
+            use_timeout = isinstance(strategy, (BasicS2C2, GeneralS2C2))
+            factor = 1.0 + slack if use_timeout else 20.0
+            # §4.3 under pipelining: the timeout clock runs on each worker's
+            # SERVICE time (from when it began the task — workers stamp
+            # ``t_start`` into their events), not from dispatch.  A task still
+            # queued behind other rounds' work gets a dispatch-anchored
+            # allowance stretched by the live backlog instead.  At inflight=1
+            # start ≈ dispatch and this reduces exactly to the paper's rule.
+            window = max(planned, 1e-3)     # per-worker virtual-time allowance
+            window_frozen = False           # set by k-finisher arming / waves
+            floor_deadline = 0.0            # explicit extensions (no-target case)
+            waves = 0
+            mispredicted = False
 
-        def current_deadline() -> float:
-            backlog = max(1, self.inflight_rounds())
-            dls = [floor_deadline]
-            for w in state.tasks:
-                # a worker with no outstanding chunks owes nothing — its
-                # work completed, was retracted away, or it was cancelled /
-                # declared failed.  Retracted chunks therefore never earn
-                # their (former) owner deadline credit.
-                if w in state.cancelled or not state.outstanding[w]:
-                    continue
-                if np.isfinite(state.start_t[w]):
-                    dls.append(state.start_t[w] + window * factor)
-                else:
-                    dls.append(state.dispatch_t[w]
-                               + window * factor * backlog)
-            return max(dls)
+            def current_deadline() -> float:
+                backlog = max(1, self.inflight_rounds())
+                dls = [floor_deadline]
+                for w in state.tasks:
+                    # a worker with no outstanding chunks owes nothing — its
+                    # work completed, was retracted away, or it was cancelled /
+                    # declared failed.  Retracted chunks therefore never earn
+                    # their (former) owner deadline credit.
+                    if w in state.cancelled or not state.outstanding[w]:
+                        continue
+                    if np.isfinite(state.start_t[w]):
+                        dls.append(state.start_t[w] + window * factor)
+                    else:
+                        dls.append(state.dispatch_t[w]
+                                   + window * factor * backlog)
+                return max(dls)
 
-        last_arrival = t0
-        while state.need > 0:
-            now = time.perf_counter()
-            # clamp every wait to the starvation bound: starvation_timeout
-            # of total event silence is a liveness failure no matter how
-            # far away the (possibly enormous, e.g. dead-worker-dominated)
-            # planned deadline sits
-            deadline = current_deadline()
-            wait = min(max(deadline - now, 1e-4), cfg.starvation_timeout)
-            try:
-                ev = inbox.get(timeout=wait)
-                if isinstance(ev, _EngineClosedSentinel):
-                    raise EngineClosed(
-                        f"round {rid}: engine shut down mid-round")
-            except queue.Empty:
+            last_arrival = t0
+            while state.need > 0:
                 now = time.perf_counter()
-                # liveness reference: while reassign waves remain, a busy
-                # pool (events for ANY round) buys this round time — FIFO
-                # guarantees its queued tasks get served.  Once waves are
-                # exhausted, only events for THIS round count: other
-                # tenants' progress must not keep an undecodable round
-                # (> n-k fail-stopped workers) blocked forever.
-                ref = (last_arrival if waves > cfg.max_reassign_waves
-                       else max(last_arrival, self._engine_last_event()))
-                if now - ref >= cfg.starvation_timeout:
-                    # dump the stuck coverage state: which chunks are
-                    # short, who covers them, who still owes them
-                    detail = "; ".join(
-                        f"chunk {c}: covered={sorted(state.used[c])} "
-                        f"assigned={sorted(w for w in range(n) if c in state.assigned[w])} "
-                        f"outstanding={sorted(w for w in range(n) if c in state.outstanding[w])}"
-                        for c in range(C) if len(state.used[c]) < k)
-                    raise RuntimeError(
-                        f"cluster starved: round {rid} got no events for "
-                        f"{cfg.starvation_timeout}s (need={state.need}; "
-                        f"cancelled={sorted(state.cancelled)}; "
-                        f"dead={sorted(self.dead)}; "
-                        f"orphans={sorted(state.orphans)}; {detail})")
-                if now < current_deadline():
-                    continue            # clamped probe, deadline not reached
-                if not np.isfinite(state.finish_t).any():
-                    # nobody has finished yet — a §4.3 wave needs a finished
-                    # worker to reassign TO, so extend instead of burning
-                    # one; the clamped wait above still errors out a fully
-                    # dead cluster.
+                # clamp every wait to the starvation bound: starvation_timeout
+                # of total event silence is a liveness failure no matter how
+                # far away the (possibly enormous, e.g. dead-worker-dominated)
+                # planned deadline sits
+                deadline = current_deadline()
+                wait = min(max(deadline - now, 1e-4), cfg.starvation_timeout)
+                try:
+                    ev = inbox.get(timeout=wait)
+                    if isinstance(ev, _EngineClosedSentinel):
+                        raise EngineClosed(
+                            f"round {rid}: engine shut down mid-round")
+                except queue.Empty:
+                    now = time.perf_counter()
+                    # liveness reference: while reassign waves remain, a busy
+                    # pool (events for ANY round) buys this round time — FIFO
+                    # guarantees its queued tasks get served.  Once waves are
+                    # exhausted, only events for THIS round count: other
+                    # tenants' progress must not keep an undecodable round
+                    # (> n-k fail-stopped workers) blocked forever.
+                    ref = (last_arrival if waves > cfg.max_reassign_waves
+                           else max(last_arrival, self._engine_last_event()))
+                    if now - ref >= cfg.starvation_timeout:
+                        # dump the stuck coverage state: which chunks are
+                        # short, who covers them, who still owes them
+                        detail = "; ".join(
+                            f"chunk {c}: covered={sorted(state.used[c])} "
+                            f"assigned={sorted(w for w in range(n) if c in state.assigned[w])} "
+                            f"outstanding={sorted(w for w in range(n) if c in state.outstanding[w])}"
+                            for c in range(C) if len(state.used[c]) < k)
+                        raise RuntimeError(
+                            f"cluster starved: round {rid} got no events for "
+                            f"{cfg.starvation_timeout}s (need={state.need}; "
+                            f"cancelled={sorted(state.cancelled)}; "
+                            f"dead={sorted(self.dead)}; "
+                            f"orphans={sorted(state.orphans)}; {detail})")
+                    if now < current_deadline():
+                        continue            # clamped probe, deadline not reached
+                    if not np.isfinite(state.finish_t).any():
+                        # nobody has finished yet — a §4.3 wave needs a finished
+                        # worker to reassign TO, so extend instead of burning
+                        # one; the clamped wait above still errors out a fully
+                        # dead cluster.
+                        floor_deadline = time.perf_counter() + window * factor
+                        continue
+                    # timeout fired with coverage incomplete (§4.3 mis-prediction
+                    # path; for MDSCoded only the generous liveness bound)
+                    mispredicted = mispredicted or use_timeout
+                    waves += 1
+                    if self.tracer.enabled:
+                        self.tracer.emit(obs.KIND_WAVE, round_id=rid, wave=waves,
+                                         need=state.need)
+                    logger.debug("round %d: §4.3 wave %d fired (need=%d)",
+                                 rid, waves, state.need)
+                    if waves > cfg.max_reassign_waves:
+                        # final: wait out the starvation bound (the no-events
+                        # check above trips it if nothing more arrives)
+                        floor_deadline = time.perf_counter() + \
+                            2 * cfg.starvation_timeout
+                        continue
+                    extra_planned = self._reassign_wave(state, rid, iteration,
+                                                        data, x, t0)
+                    window = max(extra_planned, 1e-3)
+                    window_frozen = True
                     floor_deadline = time.perf_counter() + window * factor
                     continue
-                # timeout fired with coverage incomplete (§4.3 mis-prediction
-                # path; for MDSCoded only the generous liveness bound)
-                mispredicted = mispredicted or use_timeout
-                waves += 1
-                if self.tracer.enabled:
-                    self.tracer.emit(obs.KIND_WAVE, round_id=rid, wave=waves,
-                                     need=state.need)
-                logger.debug("round %d: §4.3 wave %d fired (need=%d)",
-                             rid, waves, state.need)
-                if waves > cfg.max_reassign_waves:
-                    # final: wait out the starvation bound (the no-events
-                    # check above trips it if nothing more arrives)
-                    floor_deadline = time.perf_counter() + \
-                        2 * cfg.starvation_timeout
-                    continue
-                extra_planned = self._reassign_wave(state, rid, iteration,
-                                                    data, x, t0)
-                window = max(extra_planned, 1e-3)
-                window_frozen = True
-                floor_deadline = time.perf_counter() + window * factor
-                continue
 
-            last_arrival = time.perf_counter()
-            if isinstance(ev, WorkerFailed):
-                if ev.round_id != rid:
+                last_arrival = time.perf_counter()
+                if isinstance(ev, WorkerFailed):
+                    if ev.round_id != rid:
+                        continue
+                    w = ev.worker
+                    state.last_event_t[w] = ev.t
+                    state.failures.append(f"worker {w}: {ev.error}")
+                    state.failed_workers.add(w)
+                    # remember what the worker had in flight at fence time: any
+                    # of these chunks arriving FROM IT later is partition-era
+                    # replay, however the rejoin races the event retransmits
+                    state.partition_claims.setdefault(w, set()).update(
+                        state.outstanding[w])
+                    state.cancelled.add(w)      # stop awaiting it on deadlines
+                    lost = sorted(c for c in state.outstanding[w]
+                                  if len(state.used[c]) < k)
+                    logger.debug("round %d: worker %d failed with outstanding=%s"
+                                 " lost=%s", rid, w,
+                                 sorted(state.outstanding[w]), lost)
+                    state.outstanding[w].clear()
+                    # fail over NOW: the crashed worker's uncovered obligation
+                    # moves to live workers without waiting for a §4.3 timeout.
+                    # Whatever cannot be placed yet (all survivors busy) is
+                    # parked as an orphan and retried at each idle transition.
+                    if lost:
+                        state.orphans |= self._failover_dispatch(
+                            state, rid, iteration, data, x, w, lost)
                     continue
-                w = ev.worker
-                state.last_event_t[w] = ev.t
-                state.failures.append(f"worker {w}: {ev.error}")
-                state.failed_workers.add(w)
-                # remember what the worker had in flight at fence time: any
-                # of these chunks arriving FROM IT later is partition-era
-                # replay, however the rejoin races the event retransmits
-                state.partition_claims.setdefault(w, set()).update(
-                    state.outstanding[w])
-                state.cancelled.add(w)      # stop awaiting it on deadlines
-                lost = sorted(c for c in state.outstanding[w]
-                              if len(state.used[c]) < k)
-                logger.debug("round %d: worker %d failed with outstanding=%s"
-                             " lost=%s", rid, w,
-                             sorted(state.outstanding[w]), lost)
-                state.outstanding[w].clear()
-                # fail over NOW: the crashed worker's uncovered obligation
-                # moves to live workers without waiting for a §4.3 timeout.
-                # Whatever cannot be placed yet (all survivors busy) is
-                # parked as an orphan and retried at each idle transition.
-                if lost:
-                    state.orphans |= self._failover_dispatch(
-                        state, rid, iteration, data, x, w, lost)
-                continue
-            if isinstance(ev, WorkerRejoined):
-                # the worker is back in planning: credits it earns from
-                # here on are fresh work, not partition-era replay
-                state.failed_workers.discard(ev.worker)
-                continue
-            if isinstance(ev, WorkerDone):
-                if ev.round_id != rid:
+                if isinstance(ev, WorkerRejoined):
+                    # the worker is back in planning: credits it earns from
+                    # here on are fresh work, not partition-era replay
+                    state.failed_workers.discard(ev.worker)
                     continue
-                if ev.cancelled:
-                    # ack (cancel / eviction / fully-retracted task): the
-                    # now-idle worker may be refilled by a steal.  Its
-                    # outstanding ledger is NOT cleared here — the ack does
-                    # not say which task it closes, and a stale drained-ack
-                    # racing a fresh re-dispatch must not wipe the fresh
-                    # chunks' deadline tracking.  The master clears the
-                    # ledger itself at each point it abandons work
-                    # (retraction, wave cancel, failure).
+                if isinstance(ev, WorkerDone):
+                    if ev.round_id != rid:
+                        continue
+                    if ev.cancelled:
+                        # ack (cancel / eviction / fully-retracted task): the
+                        # now-idle worker may be refilled by a steal.  Its
+                        # outstanding ledger is NOT cleared here — the ack does
+                        # not say which task it closes, and a stale drained-ack
+                        # racing a fresh re-dispatch must not wipe the fresh
+                        # chunks' deadline tracking.  The master clears the
+                        # ledger itself at each point it abandons work
+                        # (retraction, wave cancel, failure).
+                        self._retry_orphans(state, rid, iteration, data, x)
+                        self._steal_pass(state, rid, iteration, data, x,
+                                         ev.worker)
+                        continue
+                    # a stale done (new work dispatched since) must not mark
+                    # the worker finished — nor re-anchor the §4.3 deadline
+                    # clock to the OLD task's start — while fresh chunks are
+                    # pending (the fresh task's own events will stamp start_t)
+                    if not state.outstanding[ev.worker]:
+                        state.finish_t[ev.worker] = ev.t
+                        state.start_t[ev.worker] = ev.t_start
+                    state.last_event_t[ev.worker] = ev.t
+                    if not np.isfinite(state.first_start_t[ev.worker]):
+                        state.first_start_t[ev.worker] = ev.t_start
+                    if use_timeout and not window_frozen:
+                        finished = np.isfinite(state.finish_t)
+                        if int(finished.sum()) >= k:
+                            # §4.3: clock = mean SERVICE time of the first k
+                            # responders, floored by the master's own planned
+                            # makespan
+                            service = state.finish_t[finished] - \
+                                state.start_t[finished]
+                            durations = np.sort(service)[:k]
+                            window = max(float(durations.mean()), planned)
+                            window_frozen = True
+                    # the finisher is idle (or about to be): place any parked
+                    # failover orphans first, then steal queued coverage from
+                    # the most backlogged workers into it
                     self._retry_orphans(state, rid, iteration, data, x)
-                    self._steal_pass(state, rid, iteration, data, x,
-                                     ev.worker)
+                    self._steal_pass(state, rid, iteration, data, x, ev.worker)
                     continue
-                # a stale done (new work dispatched since) must not mark
-                # the worker finished — nor re-anchor the §4.3 deadline
-                # clock to the OLD task's start — while fresh chunks are
-                # pending (the fresh task's own events will stamp start_t)
-                if not state.outstanding[ev.worker]:
-                    state.finish_t[ev.worker] = ev.t
-                    state.start_t[ev.worker] = ev.t_start
-                state.last_event_t[ev.worker] = ev.t
-                if not np.isfinite(state.first_start_t[ev.worker]):
-                    state.first_start_t[ev.worker] = ev.t_start
-                if use_timeout and not window_frozen:
-                    finished = np.isfinite(state.finish_t)
-                    if int(finished.sum()) >= k:
-                        # §4.3: clock = mean SERVICE time of the first k
-                        # responders, floored by the master's own planned
-                        # makespan
-                        service = state.finish_t[finished] - \
-                            state.start_t[finished]
-                        durations = np.sort(service)[:k]
-                        window = max(float(durations.mean()), planned)
-                        window_frozen = True
-                # the finisher is idle (or about to be): place any parked
-                # failover orphans first, then steal queued coverage from
-                # the most backlogged workers into it
-                self._retry_orphans(state, rid, iteration, data, x)
-                self._steal_pass(state, rid, iteration, data, x, ev.worker)
-                continue
-            if not isinstance(ev, ChunkDone) or ev.round_id != rid:
-                continue
-            w, c = ev.worker, ev.chunk_id
-            state.last_event_t[w] = ev.t
-            state.start_t[w] = ev.t_start
-            if not np.isfinite(state.first_start_t[w]):
-                state.first_start_t[w] = ev.t_start
-            state.chunks_done[w] += 1
-            state.outstanding[w].discard(c)
-            if len(state.used[c]) < k and w not in state.covered_by[c]:
-                state.covered_by[c].add(w)
-                state.used[c].append(w)
-                state.partials[(w, c)] = ev.result
-                state.need -= 1
-                if self.journal is not None:
-                    # durable ack: recovery seeds this credit verbatim
-                    # (the result rides along for a bit-identical decode)
-                    self._journal("ack", {
-                        "rid": rid, "chunk": c, "worker": w,
-                        "result": encode_array(ev.result)})
-                claims = state.partition_claims.get(w)
-                if w in state.failed_workers or (claims and c in claims):
-                    # partition-era work replayed after heal: credited,
-                    # never recomputed (arXiv:1804.10331's argument that
-                    # every unit of completed work should count).  The
-                    # claim set matters because the rejoin handshake rides
-                    # cheap control frames and usually un-fences the worker
-                    # BEFORE its buffered event retransmits drain.
-                    if claims:
-                        claims.discard(c)
-                    state.partition_credits += 1
-                    self._m_partition_credits.labels(
-                        transport=self._transport_kind).inc()
-                    if self.tracer.enabled:
-                        self.tracer.emit(obs.KIND_PARTITION_CREDIT,
-                                         worker=w, round_id=rid,
-                                         chunk_id=c)
-                if len(state.used[c]) >= k:
-                    state.pending.discard(c)    # fully covered
-                    state.orphans.discard(c)
-            else:
-                state.wasted_chunks[w] += 1
-            if not state.outstanding[w]:
-                # this worker just went idle-in-round: an earlier verdict
-                # may have parked orphans waiting for exactly this moment
-                self._retry_orphans(state, rid, iteration, data, x)
-            # chunk-granular idle scan: a worker idled by ANOTHER round's
-            # completion sends this round no event, so piggyback a cheap
-            # sweep on our own chunk stream
-            self._steal_sweep(state, rid, iteration, data, x)
+                if not isinstance(ev, ChunkDone) or ev.round_id != rid:
+                    continue
+                w, c = ev.worker, ev.chunk_id
+                obs.add_cost(state.cost, ev.cost)
+                state.last_event_t[w] = ev.t
+                state.start_t[w] = ev.t_start
+                if not np.isfinite(state.first_start_t[w]):
+                    state.first_start_t[w] = ev.t_start
+                state.chunks_done[w] += 1
+                state.outstanding[w].discard(c)
+                if len(state.used[c]) < k and w not in state.covered_by[c]:
+                    state.covered_by[c].add(w)
+                    state.used[c].append(w)
+                    state.partials[(w, c)] = ev.result
+                    state.need -= 1
+                    if self.journal is not None:
+                        # durable ack: recovery seeds this credit verbatim
+                        # (the result rides along for a bit-identical decode)
+                        self._journal("ack", {
+                            "rid": rid, "chunk": c, "worker": w,
+                            "result": encode_array(ev.result)})
+                    claims = state.partition_claims.get(w)
+                    if w in state.failed_workers or (claims and c in claims):
+                        # partition-era work replayed after heal: credited,
+                        # never recomputed (arXiv:1804.10331's argument that
+                        # every unit of completed work should count).  The
+                        # claim set matters because the rejoin handshake rides
+                        # cheap control frames and usually un-fences the worker
+                        # BEFORE its buffered event retransmits drain.
+                        if claims:
+                            claims.discard(c)
+                        state.partition_credits += 1
+                        self._m_partition_credits.labels(
+                            transport=self._transport_kind).inc()
+                        if self.tracer.enabled:
+                            self.tracer.emit(obs.KIND_PARTITION_CREDIT,
+                                             worker=w, round_id=rid,
+                                             chunk_id=c)
+                    if len(state.used[c]) >= k:
+                        state.pending.discard(c)    # fully covered
+                        state.orphans.discard(c)
+                else:
+                    state.wasted_chunks[w] += 1
+                if not state.outstanding[w]:
+                    # this worker just went idle-in-round: an earlier verdict
+                    # may have parked orphans waiting for exactly this moment
+                    self._retry_orphans(state, rid, iteration, data, x)
+                # chunk-granular idle scan: a worker idled by ANOTHER round's
+                # completion sends this round no event, so piggyback a cheap
+                # sweep on our own chunk stream
+                self._steal_sweep(state, rid, iteration, data, x)
 
-        t_collected = time.perf_counter()
-        # cancel everything still running — the round is decodable
-        for w, task in state.tasks.items():
-            if not np.isfinite(state.finish_t[w]):
-                self.workers[w].cancel_task(task)
-                state.cancelled.add(w)
+            collect.note(waves=waves, steals=state.steals,
+                         retracted=state.retracted)
+        with obs.span("s2c2.round.decode", self.tracer, obs.KIND_ROUND_DECODE,
+                      round_id=rid) as decode:
+            # cancel everything still running — the round is decodable
+            for w, task in state.tasks.items():
+                if not np.isfinite(state.finish_t[w]):
+                    self.workers[w].cancel_task(task)
+                    state.cancelled.add(w)
 
-        # decode from exactly-k coverage: gather the used results compactly
-        # (no dense (n, C, rpc) scratch) and run one batched contraction
-        # into a preallocated block-major buffer (CodedData.decode_compact).
-        # gather_used sorts each chunk's responders, so the decode depends
-        # only on the coverage SET — stealing-on and stealing-off decode
-        # bit-identically whenever coverage matches.
-        ids, y_parts = data.gather_used(state.used, state.partials)
-        dms = data.code.decode_submats(ids)
-        y = data.decode_compact(dms, y_parts,
-                                use_kernel=cfg.decode_with_kernel)
-        t_done = time.perf_counter()
-
-        if self.tracer.enabled:
-            emit = self.tracer.emit
-            emit(obs.KIND_ROUND_PLAN, round_id=rid, t=t_plan0,
-                 dur=t0 - t_plan0, strategy=type(strategy).__name__)
-            emit(obs.KIND_ROUND_DISPATCH, round_id=rid, t=t0,
-                 dur=t_disp - t0)
-            emit(obs.KIND_ROUND_COLLECT, round_id=rid, t=t_disp,
-                 dur=t_collected - t_disp, waves=waves,
-                 steals=state.steals, retracted=state.retracted)
-            emit(obs.KIND_ROUND_DECODE, round_id=rid, t=t_collected,
-                 dur=t_done - t_collected)
+            # decode from exactly-k coverage: gather the used results compactly
+            # (no dense (n, C, rpc) scratch) and run one batched contraction
+            # into a preallocated block-major buffer (CodedData.decode_compact).
+            # gather_used sorts each chunk's responders, so the decode depends
+            # only on the coverage SET — stealing-on and stealing-off decode
+            # bit-identically whenever coverage matches.
+            ids, y_parts = data.gather_used(state.used, state.partials)
+            dms = data.code.decode_submats(ids)
+            y = data.decode_compact(dms, y_parts,
+                                    use_kernel=cfg.decode_with_kernel)
+        host_cpu_s = time.thread_time() - cpu0
+        t_collected, t_done = collect.t1, decode.t1
+        cost = obs.CallCost(*state.cost)
 
         # measured speeds: rows · row_cost / response time (§6.2's l_i/t_i).
         # Only silent workers (zero events while allocated) count as
@@ -1474,7 +1498,7 @@ class CodedExecutionEngine:
         metrics = RoundMetrics(
             round_id=rid, strategy=type(strategy).__name__,
             makespan=t_done - t0, compute_time=t_collected - t0,
-            decode_time=t_done - t_collected, useful_rows=useful,
+            decode_time=decode.dur, useful_rows=useful,
             wasted_rows=wasted,
             speeds_measured=np.where(np.isfinite(speeds), speeds, 0.0),
             planned_makespan=planned, reassign_waves=waves,
@@ -1484,7 +1508,10 @@ class CodedExecutionEngine:
             steals=state.steals, retracted_chunks=state.retracted,
             worker_failures=tuple(state.failures),
             recovered_chunks=state.recovered_chunks,
-            partition_credits=state.partition_credits)
+            partition_credits=state.partition_credits,
+            chunk_calls=cost.calls, chunk_wall_s=cost.wall_s,
+            chunk_cpu_calls=cost.cpu_calls, chunk_cpu_s=cost.cpu_s,
+            host_cpu_s=host_cpu_s)
         self._publish_round(metrics, state.chunks_done)
         if self.journal is not None:
             self._journal("retire", {"rid": rid})
@@ -1751,167 +1778,168 @@ class CodedExecutionEngine:
         # under _obs_lock from every concurrent driver
         with self._obs_lock:
             iteration = self.iteration
-        t0 = time.perf_counter()
-        rpp = data.rows_per_part
-        width = rhs_width(x)            # replicated rounds are width-generic
-        work_per_part = rpp * width * cfg.row_cost
+        cpu0 = time.thread_time()
+        with obs.span("s2c2.round.dispatch", self.tracer,
+                      obs.KIND_ROUND_DISPATCH, round_id=rid,
+                      strategy=type(strategy).__name__) as dispatch:
+            rpp = data.rows_per_part
+            width = rhs_width(x)            # replicated rounds are width-generic
+            work_per_part = rpp * width * cfg.row_cost
 
-        results: List[Optional[np.ndarray]] = [None] * n_parts
-        attempt_owner: Dict[int, List[int]] = {p: [] for p in range(n_parts)}
-        tasks: Dict[Tuple[int, int], ChunkTask] = {}
-        busy: Set[int] = set()
-        finish_t = np.full(n, np.nan)
-        rows_done = np.zeros(n)
-        wasted = np.zeros(n)
+            results: List[Optional[np.ndarray]] = [None] * n_parts
+            attempt_owner: Dict[int, List[int]] = {p: [] for p in range(n_parts)}
+            tasks: Dict[Tuple[int, int], ChunkTask] = {}
+            busy: Set[int] = set()
+            finish_t = np.full(n, np.nan)
+            rows_done = np.zeros(n)
+            wasted = np.zeros(n)
 
-        def launch(p: int, w: int) -> None:
-            task = ChunkTask(round_id=rid, iteration=iteration,
-                             shard_id=data.part_shard_id(p),
-                             chunks=[(p, 0, rpp)], x=x,
-                             row_cost=cfg.row_cost, cancel=threading.Event())
-            tasks[(p, w)] = task
-            attempt_owner[p].append(w)
-            busy.add(w)
-            if self.tracer.enabled:
-                self.tracer.emit(obs.KIND_ENQUEUE, worker=w, round_id=rid,
-                                 chunk_id=p)
-            self.workers[w].submit(task)
+            def launch(p: int, w: int) -> None:
+                task = ChunkTask(round_id=rid, iteration=iteration,
+                                 shard_id=data.part_shard_id(p),
+                                 chunks=[(p, 0, rpp)], x=x,
+                                 row_cost=cfg.row_cost, cancel=threading.Event())
+                tasks[(p, w)] = task
+                attempt_owner[p].append(w)
+                busy.add(w)
+                if self.tracer.enabled:
+                    self.tracer.emit(obs.KIND_ENQUEUE, worker=w, round_id=rid,
+                                     chunk_id=p)
+                self.workers[w].submit(task)
 
-        for p in range(n_parts):
-            launch(p, int(data.placement[p][0]))
-        t_disp = time.perf_counter()
+            for p in range(n_parts):
+                launch(p, int(data.placement[p][0]))
+        t0 = dispatch.t0
 
-        spec_budget = strategy.max_speculative
-        n_done = 0
-        deadline = t0 + n_parts * work_per_part * 20    # liveness bound
-        speculated = False
-        last_arrival = t0
-        while n_done < n_parts:
-            now = time.perf_counter()
-            wait = min(max(deadline - now, 1e-4), cfg.starvation_timeout)
-            try:
-                ev = inbox.get(timeout=wait)
-                if isinstance(ev, _EngineClosedSentinel):
-                    raise EngineClosed(
-                        f"replicated round {rid}: engine shut down mid-round")
-            except queue.Empty:
+        with obs.span("s2c2.round.collect", self.tracer,
+                      obs.KIND_ROUND_COLLECT, round_id=rid) as collect:
+            spec_budget = strategy.max_speculative
+            costs = list(obs.NO_COST)
+            n_done = 0
+            deadline = t0 + n_parts * work_per_part * 20    # liveness bound
+            speculated = False
+            last_arrival = t0
+            while n_done < n_parts:
                 now = time.perf_counter()
-                if now - max(last_arrival, self._engine_last_event()) >= \
-                        cfg.starvation_timeout:
-                    raise RuntimeError(
-                        f"replicated round {rid}: no events for "
-                        f"{cfg.starvation_timeout}s "
-                        f"({n_parts - n_done} partitions pending)")
-                if now < deadline:
-                    continue            # clamped probe, deadline not reached
-                # a primary died with no idle replica holder: force-launch
-                # every pending partition on ANY idle alive worker holding a
-                # replica.  Keep waiting while an already-launched attempt
-                # is still in flight on a worker not known dead — the
-                # deadline here is VIRTUAL time, and a loaded host can
-                # stretch real service far past it, so in-flight attempts
-                # are only abandoned on REAL silence: if the round has seen
-                # no event at all for starvation_timeout, the attempts are
-                # presumed fail-stopped.  (An extension-count cap here used
-                # to mis-declare busy-but-alive attempts unrecoverable
-                # whenever the host was contended.)
-                progressed = False
-                in_flight = False
-                for p in range(n_parts):
-                    if results[p] is not None:
-                        continue
-                    holders = [int(h) for h in data.placement[p]
-                               if int(h) not in busy
-                               and int(h) not in self.dead
-                               and int(h) not in attempt_owner[p]]
-                    if holders:
-                        launch(p, holders[0])
-                        progressed = True
-                    elif any(w in busy and w not in self.dead
-                             for w in attempt_owner[p]):
-                        in_flight = True
-                if not progressed and not in_flight:
-                    raise RuntimeError(
-                        f"replicated round {rid}: {n_parts - n_done} "
-                        "partitions unrecoverable (all replicas dead?)")
-                if not progressed and \
-                        now - last_arrival >= cfg.starvation_timeout:
-                    raise RuntimeError(
-                        f"replicated round {rid}: {n_parts - n_done} "
-                        "partitions stuck — in-flight attempts silent for "
-                        f"{cfg.starvation_timeout}s (fail-stopped replicas?)")
-                deadline = time.perf_counter() + n_parts * work_per_part * 20
-                continue
-
-            last_arrival = time.perf_counter()
-            if isinstance(ev, WorkerFailed):
-                if ev.round_id != rid:
+                wait = min(max(deadline - now, 1e-4), cfg.starvation_timeout)
+                try:
+                    ev = inbox.get(timeout=wait)
+                    if isinstance(ev, _EngineClosedSentinel):
+                        raise EngineClosed(
+                            f"replicated round {rid}: engine shut down mid-round")
+                except queue.Empty:
+                    now = time.perf_counter()
+                    if now - max(last_arrival, self._engine_last_event()) >= \
+                            cfg.starvation_timeout:
+                        raise RuntimeError(
+                            f"replicated round {rid}: no events for "
+                            f"{cfg.starvation_timeout}s "
+                            f"({n_parts - n_done} partitions pending)")
+                    if now < deadline:
+                        continue            # clamped probe, deadline not reached
+                    # a primary died with no idle replica holder: force-launch
+                    # every pending partition on ANY idle alive worker holding a
+                    # replica.  Keep waiting while an already-launched attempt
+                    # is still in flight on a worker not known dead — the
+                    # deadline here is VIRTUAL time, and a loaded host can
+                    # stretch real service far past it, so in-flight attempts
+                    # are only abandoned on REAL silence: if the round has seen
+                    # no event at all for starvation_timeout, the attempts are
+                    # presumed fail-stopped.  (An extension-count cap here used
+                    # to mis-declare busy-but-alive attempts unrecoverable
+                    # whenever the host was contended.)
+                    progressed = False
+                    in_flight = False
+                    for p in range(n_parts):
+                        if results[p] is not None:
+                            continue
+                        holders = [int(h) for h in data.placement[p]
+                                   if int(h) not in busy
+                                   and int(h) not in self.dead
+                                   and int(h) not in attempt_owner[p]]
+                        if holders:
+                            launch(p, holders[0])
+                            progressed = True
+                        elif any(w in busy and w not in self.dead
+                                 for w in attempt_owner[p]):
+                            in_flight = True
+                    if not progressed and not in_flight:
+                        raise RuntimeError(
+                            f"replicated round {rid}: {n_parts - n_done} "
+                            "partitions unrecoverable (all replicas dead?)")
+                    if not progressed and \
+                            now - last_arrival >= cfg.starvation_timeout:
+                        raise RuntimeError(
+                            f"replicated round {rid}: {n_parts - n_done} "
+                            "partitions stuck — in-flight attempts silent for "
+                            f"{cfg.starvation_timeout}s (fail-stopped replicas?)")
+                    deadline = time.perf_counter() + n_parts * work_per_part * 20
                     continue
-                # crashed worker: relaunch its pending partitions on idle
-                # alive replica holders right away (no waiting for the
-                # liveness probe; the collector already marked it dead)
-                busy.discard(ev.worker)
-                for p in range(n_parts):
-                    if results[p] is not None or \
-                            ev.worker not in attempt_owner[p]:
+
+                last_arrival = time.perf_counter()
+                if isinstance(ev, WorkerFailed):
+                    if ev.round_id != rid:
                         continue
-                    holders = [int(h) for h in data.placement[p]
-                               if int(h) not in busy
-                               and int(h) not in self.dead
-                               and int(h) not in attempt_owner[p]]
-                    if holders:
-                        launch(p, holders[0])
-                continue
-            if isinstance(ev, WorkerDone):
-                if ev.round_id == rid:
-                    busy.discard(ev.worker)     # idle again either way
-                    if not ev.cancelled:
-                        finish_t[ev.worker] = ev.t
-                continue
-            if not isinstance(ev, ChunkDone) or ev.round_id != rid:
-                continue
-            p, w = ev.chunk_id, ev.worker
-            rows_done[w] += rpp
-            if results[p] is None:
-                results[p] = ev.result
-                n_done += 1
-                # losers of the race: cancel + account their work as wasted
-                for ow in attempt_owner[p]:
-                    if ow != w and (p, ow) in tasks:
-                        self.workers[ow].cancel_task(tasks[(p, ow)])
-            else:
-                wasted[w] += rpp
+                    # crashed worker: relaunch its pending partitions on idle
+                    # alive replica holders right away (no waiting for the
+                    # liveness probe; the collector already marked it dead)
+                    busy.discard(ev.worker)
+                    for p in range(n_parts):
+                        if results[p] is not None or \
+                                ev.worker not in attempt_owner[p]:
+                            continue
+                        holders = [int(h) for h in data.placement[p]
+                                   if int(h) not in busy
+                                   and int(h) not in self.dead
+                                   and int(h) not in attempt_owner[p]]
+                        if holders:
+                            launch(p, holders[0])
+                    continue
+                if isinstance(ev, WorkerDone):
+                    if ev.round_id == rid:
+                        busy.discard(ev.worker)     # idle again either way
+                        if not ev.cancelled:
+                            finish_t[ev.worker] = ev.t
+                    continue
+                if not isinstance(ev, ChunkDone) or ev.round_id != rid:
+                    continue
+                p, w = ev.chunk_id, ev.worker
+                obs.add_cost(costs, ev.cost)
+                rows_done[w] += rpp
+                if results[p] is None:
+                    results[p] = ev.result
+                    n_done += 1
+                    # losers of the race: cancel + account their work as wasted
+                    for ow in attempt_owner[p]:
+                        if ow != w and (p, ow) in tasks:
+                            self.workers[ow].cancel_task(tasks[(p, ow)])
+                else:
+                    wasted[w] += rpp
 
-            # LATE-style speculation once detect_fraction of tasks landed
-            if (n_done >= strategy.detect_fraction * n_parts
-                    and spec_budget > 0):
-                speculated = True
-                pending = [p2 for p2 in range(n_parts) if results[p2] is None]
-                for p2 in pending:
-                    if spec_budget == 0:
-                        break
-                    idle_holders = [
-                        int(h) for h in data.placement[p2]
-                        if int(h) not in busy and int(h) not in self.dead
-                        and int(h) not in attempt_owner[p2]]
-                    if idle_holders:
-                        launch(p2, idle_holders[0])
-                        spec_budget -= 1
-
-        t_collected = time.perf_counter()
-        for (_p, w), task in tasks.items():
-            self.workers[w].cancel_task(task)
-        y = data.assemble(results)
-        t_done = time.perf_counter()
-
-        if self.tracer.enabled:
-            emit = self.tracer.emit
-            emit(obs.KIND_ROUND_DISPATCH, round_id=rid, t=t0,
-                 dur=t_disp - t0, strategy=type(strategy).__name__)
-            emit(obs.KIND_ROUND_COLLECT, round_id=rid, t=t_disp,
-                 dur=t_collected - t_disp, speculated=speculated)
-            emit(obs.KIND_ROUND_DECODE, round_id=rid, t=t_collected,
-                 dur=t_done - t_collected)
+                # LATE-style speculation once detect_fraction of tasks landed
+                if (n_done >= strategy.detect_fraction * n_parts
+                        and spec_budget > 0):
+                    speculated = True
+                    pending = [p2 for p2 in range(n_parts) if results[p2] is None]
+                    for p2 in pending:
+                        if spec_budget == 0:
+                            break
+                        idle_holders = [
+                            int(h) for h in data.placement[p2]
+                            if int(h) not in busy and int(h) not in self.dead
+                            and int(h) not in attempt_owner[p2]]
+                        if idle_holders:
+                            launch(p2, idle_holders[0])
+                            spec_budget -= 1
+            collect.note(speculated=speculated)
+        with obs.span("s2c2.round.decode", self.tracer, obs.KIND_ROUND_DECODE,
+                      round_id=rid) as decode:
+            for (_p, w), task in tasks.items():
+                self.workers[w].cancel_task(task)
+            y = data.assemble(results)
+        host_cpu_s = time.thread_time() - cpu0
+        t_collected, t_done = collect.t1, decode.t1
+        cost = obs.CallCost(*costs)
 
         speeds = np.full(n, np.nan)
         response = np.full(n, np.nan)
@@ -1942,11 +1970,14 @@ class CodedExecutionEngine:
         metrics = RoundMetrics(
             round_id=rid, strategy=type(strategy).__name__,
             makespan=t_done - t0, compute_time=t_collected - t0,
-            decode_time=t_done - t_collected, useful_rows=useful,
+            decode_time=decode.dur, useful_rows=useful,
             wasted_rows=wasted * width,
             speeds_measured=np.where(np.isfinite(speeds), speeds, 0.0),
             planned_makespan=work_per_part,
             mispredicted=speculated,
-            inflight=inflight, rhs_width=width)
+            inflight=inflight, rhs_width=width,
+            chunk_calls=cost.calls, chunk_wall_s=cost.wall_s,
+            chunk_cpu_calls=cost.cpu_calls, chunk_cpu_s=cost.cpu_s,
+            host_cpu_s=host_cpu_s)
         self._publish_round(metrics)
         return RoundOutput(y=y, metrics=metrics)

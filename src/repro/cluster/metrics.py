@@ -45,8 +45,8 @@ class RoundMetrics:
     coalesced: int = 1                # requests merged into this round; a
     #                                   follower's ride-along copy keeps the
     #                                   timing but zeroes the resource rows
-    #                                   so service totals count the shared
-    #                                   round exactly once
+    #                                   and host cost so service totals
+    #                                   count the shared round exactly once
     steals: int = 0                   # successful idle-triggered steal passes
     retracted_chunks: int = 0         # chunks retracted and re-dispatched
     # WorkerFailed reasons seen this round
@@ -55,6 +55,16 @@ class RoundMetrics:
     #                                   master recovery (never recomputed)
     partition_credits: int = 0        # chunks credited from a SUSPECTED
     #                                   (partitioned) worker's replay
+    # host cost, from the per-chunk records of the chunk results received
+    # (obs.CallCost: what the backend charged, so a wrapper's sleep around
+    # it is left out) and the round driver's own thread
+    chunk_calls: int = 0              # backend calls measured
+    chunk_wall_s: float = 0.0         # their wall seconds, summed
+    chunk_cpu_calls: int = 0          # those whose thread CPU was read
+    #                                   (obs.CPU_SAMPLE)
+    chunk_cpu_s: float = 0.0          # their calling threads' CPU seconds
+    host_cpu_s: float = 0.0           # round driver's thread CPU seconds,
+    #                                   plan start to decode end
 
     @property
     def total_useful(self) -> float:

@@ -1,8 +1,17 @@
 """Observability plane for the cluster engine: tracing, metrics, logging.
 
-Three instruments, all overhead-guarded so a production round pays nothing
+Four instruments, all overhead-guarded so a production round pays nothing
 measurable when they are off:
 
+* :class:`span` — the one span helper of the engine: ``s2c2.<layer>.<phase>``
+  as a ``jax.profiler.TraceAnnotation`` while a profiler session records,
+  so host spans land on the device trace's clock, and, where a
+  :class:`Tracer` is enabled, a :class:`TraceRecord` from the same start
+  and end stamps.  It never imports JAX into a process that has not.
+* :func:`charge_start` / :func:`charge_end` — always-on per-chunk
+  counters: a backend charges the wall time and (sampled) thread-CPU time
+  of its own call to the record the worker opened on that thread
+  (:func:`open_record`, :class:`CallCost`).
 * :class:`Tracer` — a bounded, thread-safe ring buffer of typed
   :class:`TraceRecord` events with monotonic (``perf_counter``)
   timestamps.  Emission is one ``enabled`` check plus a GIL-atomic deque
@@ -14,7 +23,8 @@ measurable when they are off:
 * :func:`chrome_trace_events` / :meth:`Tracer.dump` — export the record
   stream as Chrome trace-event JSON (loadable in Perfetto /
   ``chrome://tracing``): each worker renders as its own process with a
-  compute lane (chunk spans) and a queue lane (enqueue/retract instants),
+  compute lane (chunk and throttle spans) and a queue lane
+  (enqueue/retract instants),
   the master renders as pid 0 with one thread lane per round (plan /
   dispatch / collect / decode spans, §4.3 wave and steal and failover
   instants, coalescer merges, §4.4 fail-stop verdicts), and
@@ -49,6 +59,8 @@ from typing import (Dict, Iterable, List, NamedTuple, Optional, Sequence,
                     Tuple)
 
 __all__ = [
+    "span", "recording", "CallCost", "NO_COST", "CPU_SAMPLE", "add_cost",
+    "open_record", "close_record", "charge_start", "charge_end",
     "TraceRecord", "Tracer", "NULL_TRACER", "chrome_trace_events",
     "export_chrome_trace",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "log_buckets",
@@ -80,9 +92,11 @@ class TraceRecord(NamedTuple):
 
 
 #: chunk lifecycle: enqueue (master → worker inbox) → chunk (worker-stamped
-#: execution span) → or retract (provably never started).
+#: backend call) → throttle (the ``row_cost`` top-up sleep after it, if
+#: any) → or retract (provably never started).
 KIND_ENQUEUE = "enqueue"
 KIND_CHUNK = "chunk"
+KIND_THROTTLE = "throttle"
 KIND_RETRACT = "retract"
 #: master decisions, one instant each
 KIND_STEAL = "steal"
@@ -120,8 +134,9 @@ KIND_RECOVERY = "recovery"
 #: of (attach) a /dev/shm segment — name/bytes/generation ride in ``args``
 KIND_SHM = "shm"
 
-SPAN_KINDS = frozenset({KIND_CHUNK, KIND_ROUND_PLAN, KIND_ROUND_DISPATCH,
-                        KIND_ROUND_COLLECT, KIND_ROUND_DECODE})
+SPAN_KINDS = frozenset({KIND_CHUNK, KIND_THROTTLE, KIND_ROUND_PLAN,
+                        KIND_ROUND_DISPATCH, KIND_ROUND_COLLECT,
+                        KIND_ROUND_DECODE})
 COUNTER_KINDS = frozenset({KIND_INJ_SPEED, KIND_OBS_SPEED})
 MASTER_KINDS = frozenset({KIND_STEAL, KIND_WAVE, KIND_FAILOVER,
                           KIND_COALESCE, KIND_FAILSTOP_VERDICT,
@@ -239,6 +254,195 @@ class Tracer:
 #: unconditionally hold a tracer and pay one attribute check when tracing
 #: is off
 NULL_TRACER = Tracer(capacity=1, enabled=False)
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+#: ``jax.profiler.TraceAnnotation`` once this process has imported JAX.  A
+#: process without ``jax.profiler`` in ``sys.modules`` cannot be recording
+#: a profiler session, so the helper never imports JAX itself: the NumPy
+#: backend and ``SocketTransport`` children stay JAX-free.  Set once, from
+#: any thread; the racing writers all store the same class.
+_annotation_cls = None
+
+
+def recording() -> bool:
+    """Whether a profiler session records in this process: one check."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        profiler = sys.modules.get("jax.profiler")
+        if profiler is None:
+            return False
+        _annotation_cls = profiler.TraceAnnotation
+    return _annotation_cls.is_enabled()
+
+
+class span:
+    """One span of the engine, used as a context manager::
+
+        with span("s2c2.round.decode", tracer, KIND_ROUND_DECODE,
+                  round_id=rid) as s:
+            ...
+
+    * While a profiler session records (``TraceAnnotation.is_enabled()``),
+      a ``TraceAnnotation`` named ``name`` spans the block, carrying the
+      ids that tie one request's spans together (``job``, ``round``,
+      ``worker``, ``chunk``; those not given are left out).  Otherwise the
+      profiler side costs one check.
+    * Where ``tracer`` is enabled and ``kind`` is given, the block's start
+      and end stamps become one :class:`TraceRecord` of that kind, with
+      ``args`` (and whatever :meth:`note` adds) as its annotations.  A
+      block that raises records no ``TraceRecord``.
+    * ``t0``/``t1``/``dur`` are the block's ``perf_counter`` stamps, so a
+      caller's own timings come from the same stamps as both sinks.
+
+    Building and entering a span costs about a microsecond of interpreter
+    time, which a chunk's path cannot afford while nothing records (see
+    :data:`CPU_SAMPLE`): per-chunk sites open one only when
+    :func:`recording` or their tracer is enabled.
+    """
+
+    __slots__ = ("name", "tracer", "kind", "ids", "args", "t0", "t1", "_ann")
+
+    def __init__(self, name: str, tracer: Optional[Tracer] = None,
+                 kind: Optional[str] = None, *, job: int = -1,
+                 worker: int = -1, round_id: int = -1, chunk_id: int = -1,
+                 **args):
+        self.name = name
+        self.tracer = tracer
+        self.kind = kind
+        self.ids = (job, worker, round_id, chunk_id)
+        self.args = args
+        self._ann = None
+
+    @property
+    def dur(self) -> float:
+        return self.t1 - self.t0
+
+    @property
+    def recording(self) -> bool:
+        """Whether a profiler session records this span (inside the block)."""
+        return self._ann is not None
+
+    def note(self, **args) -> None:
+        """Add annotations known only inside the block (e.g. a round's
+        wave count), to the profiler's event and the trace record."""
+        self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
+    def __enter__(self) -> "span":
+        if recording():
+            ids = {k: v for k, v in zip(("job", "worker", "round", "chunk"),
+                                        self.ids) if v >= 0}
+            self._ann = _annotation_cls(self.name, **ids)
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        tracer = self.tracer
+        if tracer is not None and tracer.enabled and self.kind is not None \
+                and exc_type is None:
+            _job, worker, round_id, chunk_id = self.ids
+            tracer.emit(self.kind, worker=worker, round_id=round_id,
+                        chunk_id=chunk_id, t=self.t0, dur=self.t1 - self.t0,
+                        **self.args)
+        return False
+
+
+# ---------------------------------------------------------------------------
+# per-chunk cost counters (always on)
+# ---------------------------------------------------------------------------
+
+class CallCost(NamedTuple):
+    """What backend calls charged to one chunk (:func:`charge_start` /
+    :func:`charge_end`): the wall seconds of every call, and the calling
+    thread's CPU seconds of the calls whose CPU was read (``cpu_calls`` of
+    the ``calls``; see :data:`CPU_SAMPLE`).  A call's mean wall minus its
+    mean CPU is the time it spent off the CPU: waiting on the device, or
+    for the interpreter lock."""
+
+    calls: int = 0
+    wall_s: float = 0.0
+    cpu_calls: int = 0
+    cpu_s: float = 0.0
+
+
+def add_cost(total: List, cost: Sequence) -> None:
+    """Add one chunk's cost (the four fields of :class:`CallCost`) into a
+    running total kept as a list of the same four fields.  Summing in
+    place keeps nothing per chunk alive: a round that kept each chunk's
+    record until its end doubled the garbage collector's passes and
+    brought on a 35–49 ms full collection in every 30 s window of a B = 1
+    benchmark cell on a TPU v5e host (PERF.md)."""
+    total[0] += cost[0]
+    total[1] += cost[1]
+    total[2] += cost[2]
+    total[3] += cost[3]
+
+
+NO_COST = CallCost()
+
+#: a thread's CPU time is read on its first charged call and every
+#: CPU_SAMPLE-th after.  The read is a system call made with the
+#: interpreter lock held: 0.5 µs on a plain Linux host but 6 µs on a TPU
+#: v5e host under a gVisor kernel, where two reads on every call
+#: cost 14% of the products per second of a B = 1 cell, and one in 16
+#: calls about 2% (PERF.md).  Wall time (``perf_counter``, no system
+#: call) is read on every call.
+CPU_SAMPLE = 64
+
+#: per thread: the open per-chunk record (:func:`open_record`) and the
+#: count of charged calls; a ``threading.local``, so each thread reads and
+#: writes only its own — no lock, and nothing for the guarded-by rule to
+#: check
+_open = threading.local()
+
+
+def open_record() -> None:
+    """Open this thread's per-chunk record: the backend calls made on the
+    thread until :func:`close_record` charge it.  A worker opens one
+    around each chunk's backend call, beneath any wrapper of the backend,
+    so a wrapper's own work (a benchmark's straggler sleep) is never
+    charged."""
+    _open.rec = [0, 0.0, 0, 0.0]            # the fields of CallCost
+
+
+def close_record() -> Tuple[int, float, int, float]:
+    """Close this thread's record and return what it holds, as a value
+    that can be handed to another thread (``ChunkDone.cost``)."""
+    rec = _open.rec
+    _open.rec = None
+    return tuple(rec)
+
+
+def charge_start() -> Tuple[float, Optional[float]]:
+    """Start of a backend's own call: its wall stamp, and on the thread's
+    sampled calls its CPU stamp."""
+    n = getattr(_open, "calls", 0)
+    _open.calls = n + 1
+    return (time.perf_counter(),
+            time.thread_time() if n % CPU_SAMPLE == 0 else None)
+
+
+def charge_end(start: Tuple[float, Optional[float]]) -> None:
+    """End of a backend's own call: charge it to the thread's open record,
+    if any."""
+    wall = time.perf_counter() - start[0]
+    rec = getattr(_open, "rec", None)
+    if rec is not None:
+        rec[0] += 1
+        rec[1] += wall
+        if start[1] is not None:
+            rec[2] += 1
+            rec[3] += time.thread_time() - start[1]
 
 
 def _pid(worker: int) -> int:

@@ -93,13 +93,15 @@ def _follower_metrics(m: RoundMetrics) -> RoundMetrics:
 
     Keeps the round's timing, width, and merge count (latency accounting
     per job stays truthful) but zeroes the resource counters so
-    service-level row/steal totals count the shared round exactly once —
-    on the leader's copy.
+    service-level row/steal/host-cost totals count the shared round exactly
+    once — on the leader's copy.
     """
     return dataclasses.replace(
         m, useful_rows=np.zeros_like(m.useful_rows),
         wasted_rows=np.zeros_like(m.wasted_rows),
-        steals=0, retracted_chunks=0, worker_failures=())
+        steals=0, retracted_chunks=0, worker_failures=(),
+        chunk_calls=0, chunk_wall_s=0.0, chunk_cpu_calls=0, chunk_cpu_s=0.0,
+        host_cpu_s=0.0)
 
 
 class _CoalesceGroup:
@@ -718,35 +720,39 @@ class JobService:
                 self._resolve_closed(handle)
                 continue
             m = handle.metrics
-            m.t_start = time.perf_counter()
-            with self._lock:
-                self._in_service += 1
-                self._peak_inflight = max(self._peak_inflight,
-                                          self._in_service)
-                in_service = self._in_service
-            self._m_inflight_jobs.set(in_service)
-            data = None
-            owned = False
-            engine_closed = False
-            try:
-                data = handle.job.prepare(self.engine)
-                owned = handle.job.data is None     # shared data outlives jobs
-                handle.output = handle.job.rounds(
-                    self._exec, data, m.rounds.append)
-            except Exception as exc:          # record, don't kill the service
-                m.error = f"{type(exc).__name__}: {exc}"
-                engine_closed = isinstance(exc, EngineClosed)
-                logger.warning("job %d (%s) failed: %s", m.job_id, m.kind,
-                               m.error)
-            finally:
-                # EngineClosed is the crash itself: the children must keep
-                # their installed shards so the recovery master's rejoin
-                # handshake can revalidate them by digest.  Unloading here
-                # would race the transport teardown and strip shards over
-                # still-open connections, making rejoin unrecoverable.
-                if data is not None and owned and not engine_closed:
-                    self.engine.unload(data)
-            m.t_done = time.perf_counter()
+            with obs.span("s2c2.job", job=m.job_id) as slot:
+                m.t_start = slot.t0
+                with self._lock:
+                    self._in_service += 1
+                    self._peak_inflight = max(self._peak_inflight,
+                                              self._in_service)
+                    in_service = self._in_service
+                self._m_inflight_jobs.set(in_service)
+                data = None
+                owned = False
+                engine_closed = False
+                try:
+                    data = handle.job.prepare(self.engine)
+                    owned = handle.job.data is None     # shared data outlives jobs
+                    handle.output = handle.job.rounds(
+                        self._exec, data, m.rounds.append)
+                except Exception as exc:          # record, don't kill the service
+                    m.error = f"{type(exc).__name__}: {exc}"
+                    engine_closed = isinstance(exc, EngineClosed)
+                    logger.warning("job %d (%s) failed: %s", m.job_id, m.kind,
+                                   m.error)
+                finally:
+                    # EngineClosed is the crash itself: the children must keep
+                    # their installed shards so the recovery master's rejoin
+                    # handshake can revalidate them by digest.  Unloading here
+                    # would race the transport teardown and strip shards over
+                    # still-open connections, making rejoin unrecoverable.
+                    if data is not None and owned and not engine_closed:
+                        self.engine.unload(data)
+                if slot.recording:
+                    slot.note(rounds=",".join(str(r.round_id)
+                                              for r in m.rounds))
+            m.t_done = slot.t1
             status = "error" if m.error else "ok"
             if handle.journaled and not engine_closed:
                 # resolution is durable before the caller can observe it

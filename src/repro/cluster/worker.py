@@ -116,6 +116,10 @@ class ChunkDone:
     #                                dispatch time (tasks queue behind other
     #                                rounds'); lets the master separate
     #                                service time from queue wait
+    cost: Tuple[int, float, int, float] = obs.NO_COST  # (calls, wall_s,
+    #                                cpu_calls, cpu_s) the backend charged
+    #                                to this chunk (obs.close_record); the
+    #                                round driver sums it into RoundMetrics
 
 
 @dataclasses.dataclass
@@ -329,18 +333,43 @@ class KernelBackend:
 
     def compute_chunk(self, worker_id: int, shard_id: str, shard: np.ndarray,
                       r0: int, r1: int, x: np.ndarray) -> np.ndarray:
+        # the whole call is charged to the worker's open record; a
+        # wrapper's work around it (a benchmark's straggler sleep) is not
+        start = obs.charge_start()
         dev = self._device_shard(worker_id, shard_id, shard)
-        if x.ndim == 1:
-            out = self._ops.chunk_matvec(dev, self._device_x(x), r0, r1 - r0)
+        if obs.recording():
+            y = self._traced_chunk(worker_id, dev, x, r0, r1)
+        else:
+            # one check per call while no profiler records: no span, and
+            # np.asarray does the wait for the device itself
+            y = np.asarray(self._dispatch(dev, self._device_x_for(x), x,
+                                          r0, r1), dtype=np.float64)
+        obs.charge_end(start)
+        return y
+
+    def _traced_chunk(self, worker_id: int, dev, x: np.ndarray, r0: int,
+                      r1: int) -> np.ndarray:
+        with obs.span("s2c2.backend.x", worker=worker_id):
+            xd = self._device_x_for(x)
+        with obs.span("s2c2.backend.dispatch", worker=worker_id):
+            out = self._dispatch(dev, xd, x, r0, r1)
+        with obs.span("s2c2.backend.device_wait", worker=worker_id):
+            out.block_until_ready()
+        with obs.span("s2c2.backend.copy_back", worker=worker_id):
             return np.asarray(out, dtype=np.float64)
+
+    def _device_x_for(self, x: np.ndarray):
+        if x.ndim == 1:
+            return self._device_x(x)
         # multi-RHS chunk: bucket the batch width to the next power of two
         # (floor 1) so coalesced rounds of heterogeneous widths land on a
         # few traced shapes; zero columns cost nothing and are sliced off
         b = x.shape[1]
-        b_bucket = _next_pow2(b)
-        xd = self._device_x(x, pad_cols=b_bucket - b)
+        return self._device_x(x, pad_cols=_next_pow2(b) - b)
+
+    def _dispatch(self, dev, xd, x: np.ndarray, r0: int, r1: int):
         out = self._ops.chunk_matvec(dev, xd, r0, r1 - r0)
-        return np.asarray(out[:, :b], dtype=np.float64)
+        return out if x.ndim == 1 else out[:, :x.shape[1]]
 
     def drop_shard(self, worker_id: int, shard_id: str) -> None:
         with self._lock:
@@ -614,6 +643,13 @@ class Worker(threading.Thread):
                     tp.running = False
                     self._active = None
 
+    def _call_backend(self, task: ChunkTask, a: np.ndarray, r0: int,
+                      r1: int) -> np.ndarray:
+        if self._compute_chunk is not None:
+            return self._compute_chunk(self.worker_id, task.shard_id, a,
+                                       r0, r1, task.x)
+        return self.compute(a[r0:r1], task.x)
+
     def _purge_task(self, tp: _TaskProgress) -> None:
         """Drop every remaining queued chunk of ``tp`` (cancel/evict/death)."""
         with self._cv:
@@ -656,13 +692,24 @@ class Worker(threading.Thread):
                          task.round_id)
             self._drop_everything()
             return
-        t0 = time.perf_counter()
+        width = rhs_width(task.x)
+        # the backend call charges this chunk's record; its span (only
+        # while a profiler records or the tracer is on: a span costs the
+        # chunk path a microsecond) is the call alone, with the injected
+        # speed riding along so a slow span is attributable
+        obs.open_record()
         try:
-            if self._compute_chunk is not None:
-                y = self._compute_chunk(self.worker_id, task.shard_id, a,
-                                        r0, r1, task.x)
+            if self.tracer.enabled or obs.recording():
+                with obs.span("s2c2.worker.chunk", self.tracer,
+                              obs.KIND_CHUNK, worker=self.worker_id,
+                              round_id=task.round_id, chunk_id=chunk_id,
+                              speed=s, rows=r1 - r0, width=width) as chunk:
+                    y = self._call_backend(task, a, r0, r1)
+                t0, t_call = chunk.t0, chunk.t1
             else:
-                y = self.compute(a[r0:r1], task.x)
+                t0 = time.perf_counter()
+                y = self._call_backend(task, a, r0, r1)
+                t_call = time.perf_counter()
         except Exception as exc:
             # a backend error is NOT fail-stop silence: report the real
             # reason terminally, then go dead (every later item is dropped)
@@ -678,24 +725,21 @@ class Worker(threading.Thread):
                 f"{type(exc).__name__}: {exc}", t_start=tp.t_start))
             self._drop_everything()
             return
+        finally:
+            cost = obs.close_record()
         # a B-wide chunk is B× the work: stretch its virtual time to match,
         # or injected slowdowns would under-throttle batched rounds
-        target = (r1 - r0) * rhs_width(task.x) * task.row_cost / s
-        elapsed = time.perf_counter() - t0
-        if target > elapsed:
-            time.sleep(target - elapsed)
+        target = (r1 - r0) * width * task.row_cost / s
+        if target > t_call - t0:
+            with obs.span("s2c2.worker.throttle", self.tracer,
+                          obs.KIND_THROTTLE, worker=self.worker_id,
+                          round_id=task.round_id, chunk_id=chunk_id):
+                time.sleep(target - (t_call - t0))
         t1 = time.perf_counter()
         self.busy_s += t1 - t0
-        if self.tracer.enabled:
-            # the chunk's execution span, worker-stamped: start = compute
-            # begin, dur includes the injector's throttling sleep, and the
-            # injected speed rides along so a slow span is attributable
-            self.tracer.emit(obs.KIND_CHUNK, worker=self.worker_id,
-                             round_id=task.round_id, chunk_id=chunk_id,
-                             t=t0, dur=t1 - t0, speed=s,
-                             rows=r1 - r0, width=rhs_width(task.x))
         self.events.put(ChunkDone(self.worker_id, task.round_id,
-                                  chunk_id, y, t1, t_start=tp.t_start))
+                                  chunk_id, y, t1, t_start=tp.t_start,
+                                  cost=cost))
         with self._cv:
             tp.done += 1
             tp.remaining -= 1

@@ -244,6 +244,25 @@ class TestTracerGuard:
         """
         assert lint(tmp_path, {"obs.py": src}, select=["S2C204"]) == []
 
+    def test_obs_py_helpers_are_checked(self, tmp_path):
+        """Only the Tracer sink is exempt: a helper in obs.py that emits
+        into a tracer (the span helper) needs the guard like any site."""
+        src = """
+            class span:
+                def __exit__(self, *exc):
+                    tracer = self.tracer
+                    tracer.emit(self.kind)
+
+            class guarded:
+                def __exit__(self, *exc):
+                    tracer = self.tracer
+                    if tracer is not None and tracer.enabled:
+                        tracer.emit(self.kind)
+        """
+        found = lint(tmp_path, {"obs.py": src}, select=["S2C204"])
+        assert rules_of(found) == ["S2C204"]
+        assert "'__exit__'" in found[0].message and found[0].line == 5
+
 
 # a minimal, fully consistent wire protocol — the S2C205 happy path
 TRANSPORT_OK = """

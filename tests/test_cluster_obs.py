@@ -193,6 +193,23 @@ class TestTraceSchema:
             assert ts[obs.KIND_ROUND_PLAN] <= ts[obs.KIND_ROUND_DISPATCH] \
                 <= ts[obs.KIND_ROUND_COLLECT] <= ts[obs.KIND_ROUND_DECODE]
 
+    def test_chunk_span_ends_when_compute_ends(self):
+        """The row_cost top-up sleep is its own throttle span on the
+        worker's compute lane, after the chunk span, not inside it."""
+        records, _ = self._run_traced(NoSlowdown(), n=4, k=3, chunks=4,
+                                      rounds=2, d=120, row_cost=2e-4)
+        chunks = {(r.worker, r.round_id, r.chunk_id): r for r in records
+                  if r.kind == obs.KIND_CHUNK}
+        throttles = [r for r in records if r.kind == obs.KIND_THROTTLE]
+        assert throttles, "no chunk was topped up"
+        for th in throttles:
+            c = chunks[(th.worker, th.round_id, th.chunk_id)]
+            assert c.t + c.dur <= th.t and th.dur > 0.0
+        events = obs.chrome_trace_events(records)
+        lanes = {(ev["pid"], ev["tid"]) for ev in events
+                 if ev.get("cat") == obs.KIND_THROTTLE}
+        assert lanes and all(pid > 0 and tid == 0 for pid, tid in lanes)
+
     def test_exported_json_is_valid_chrome_trace(self, tmp_path):
         tr = Tracer()
         eng = make_engine(5, 3, FailStopInjector({0: 0, 1: 0}),
