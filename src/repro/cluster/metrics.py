@@ -55,7 +55,8 @@ class RoundMetrics:
     #                                   master recovery (never recomputed)
     partition_credits: int = 0        # chunks credited from a SUSPECTED
     #                                   (partitioned) worker's replay
-    # host cost, from the per-chunk records of the chunk results received
+    # host cost, from the per-call records of the chunk results received
+    # (each call's record rides the first chunk of the call's run)
     # (obs.CallCost: what the backend charged, so a wrapper's sleep around
     # it is left out) and the round driver's own thread
     chunk_calls: int = 0              # backend calls measured

@@ -362,12 +362,12 @@ class span:
 # ---------------------------------------------------------------------------
 
 class CallCost(NamedTuple):
-    """What backend calls charged to one chunk (:func:`charge_start` /
-    :func:`charge_end`): the wall seconds of every call, and the calling
-    thread's CPU seconds of the calls whose CPU was read (``cpu_calls`` of
-    the ``calls``; see :data:`CPU_SAMPLE`).  A call's mean wall minus its
-    mean CPU is the time it spent off the CPU: waiting on the device, or
-    for the interpreter lock."""
+    """What backend calls charged to one call of a worker
+    (:func:`charge_start` / :func:`charge_end`): the wall seconds of every
+    call, and the calling thread's CPU seconds of the calls whose CPU was
+    read (``cpu_calls`` of the ``calls``; see :data:`CPU_SAMPLE`).  A
+    call's mean wall minus its mean CPU is the time it spent off the CPU:
+    waiting on the device, or for the interpreter lock."""
 
     calls: int = 0
     wall_s: float = 0.0
@@ -376,7 +376,7 @@ class CallCost(NamedTuple):
 
 
 def add_cost(total: List, cost: Sequence) -> None:
-    """Add one chunk's cost (the four fields of :class:`CallCost`) into a
+    """Add one call's cost (the four fields of :class:`CallCost`) into a
     running total kept as a list of the same four fields.  Summing in
     place keeps nothing per chunk alive: a round that kept each chunk's
     record until its end doubled the garbage collector's passes and
@@ -399,7 +399,7 @@ NO_COST = CallCost()
 #: call) is read on every call.
 CPU_SAMPLE = 64
 
-#: per thread: the open per-chunk record (:func:`open_record`) and the
+#: per thread: the open per-call record (:func:`open_record`) and the
 #: count of charged calls; a ``threading.local``, so each thread reads and
 #: writes only its own — no lock, and nothing for the guarded-by rule to
 #: check
@@ -407,11 +407,11 @@ _open = threading.local()
 
 
 def open_record() -> None:
-    """Open this thread's per-chunk record: the backend calls made on the
+    """Open this thread's per-call record: the backend calls made on the
     thread until :func:`close_record` charge it.  A worker opens one
-    around each chunk's backend call, beneath any wrapper of the backend,
-    so a wrapper's own work (a benchmark's straggler sleep) is never
-    charged."""
+    around each backend call (one chunk, or a run of chunks), beneath any
+    wrapper of the backend, so a wrapper's own work (a benchmark's
+    straggler sleep) is never charged."""
     _open.rec = [0, 0.0, 0, 0.0]            # the fields of CallCost
 
 

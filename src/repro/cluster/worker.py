@@ -3,11 +3,20 @@
 A worker owns a shard store (``shard_id -> np.ndarray`` of coded rows, one
 entry per tenant job), a **retractable deque** of per-chunk work items, and
 pushes :class:`ChunkDone` / :class:`WorkerDone` events to the master's
-single event queue.  Chunks are computed *one at a time, in queue order* —
-that is what makes partial work and out-of-order any-k collection real:
-the master sees chunk-granular completions interleaved across workers and
+single event queue.  Chunks are computed *in queue order*, one backend
+call at a time, and each reports its own :class:`ChunkDone` — that is
+what makes partial work and out-of-order any-k collection real: the
+master sees chunk-granular completions interleaved across workers and
 can stop, cancel, reassign, **retract**, or **reprioritize** between any
-two of them.
+two calls.
+
+A shard-aware backend (below) is handed a *run* of queued chunks in one
+call: the head item and the items behind it of the same task whose rows
+continue it, up to :data:`RUN_MAX_ROWS` rows and at most half (rounded
+up) of the task's queued chunks, so a backlog stays behind the run for
+retraction and the steal pass.  The call's result is split back into one
+:class:`ChunkDone` per chunk.  A plain ``compute(a_rows, x)`` backend has
+no per-call cost worth sharing and keeps one call per chunk.
 
 The inbox is chunk-granular on purpose (the work-stealing substrate): a
 dispatched :class:`ChunkTask` is split into one queue item per chunk, and
@@ -22,8 +31,8 @@ the master may
 * observe :meth:`Worker.backlog` / :meth:`Worker.idle` to drive the
   idle-triggered steal pass.
 
-Speed injection: before each chunk the worker asks its injector for the
-current speed ``s`` and stretches the chunk to ``rows · B · row_cost / s``
+Speed injection: before each call the worker asks its injector for the
+current speed ``s`` and stretches the call to ``rows · B · row_cost / s``
 seconds of wall time, where ``B`` is the RHS width (compute runs natively;
 the remainder is slept, so the throttling is real wall-clock, not
 bookkeeping).  A multi-RHS chunk does ``B×`` the work of a matvec chunk,
@@ -39,14 +48,15 @@ instead of waiting out the §4.4 silence detector.
 The compute backend is pluggable: the default is plain BLAS
 (``a[rows] @ x`` — a BLAS-2 matvec for a 1-D operand, one BLAS-3 GEMM for
 an ``(d, B)`` multi-RHS block); :class:`KernelBackend` (via
-:func:`kernel_backend`) routes each chunk through the Pallas
+:func:`kernel_backend`) routes each call through the Pallas
 ``coded_matvec`` kernel (interpret mode on the CPU backend) — same semantics,
 exercised by the demo to prove the engine drives ``repro.kernels``.  A
 backend may additionally implement the shard-aware protocol
 (``compute_chunk(worker_id, shard_id, shard, r0, r1, x)`` plus optional
 ``drop_shard(worker_id, shard_id)``): the worker then hands it the whole
-shard and the chunk range, which lets the backend keep a device-resident
-copy of each shard instead of re-uploading rows on every chunk.
+shard and the row range of a run, which lets the backend keep a
+device-resident copy of each shard instead of re-uploading rows on every
+call.
 """
 
 from __future__ import annotations
@@ -68,11 +78,18 @@ from repro.cluster.obs import NULL_TRACER, Tracer
 
 __all__ = ["ChunkTask", "ChunkDone", "WorkerDone", "WorkerFailed",
            "WorkerRejoined", "Worker", "numpy_backend", "kernel_backend",
-           "KernelBackend", "rhs_width", "shard_digest"]
+           "KernelBackend", "rhs_width", "shard_digest", "RUN_MAX_ROWS"]
 
 logger = logging.getLogger("repro.cluster.worker")
 
 ComputeFn = Callable[[np.ndarray, np.ndarray], np.ndarray]
+
+#: Most rows one call of a shard-aware backend takes for a run of chunks:
+#: the kernel's one-block limit, ``repro.kernels.ops.MAX_BLOCK_ROWS``
+#: (kept here so that this module never imports JAX).  A run of that size
+#: is one kernel block with no padded rows; chunks of more than half of
+#: it run one per call.
+RUN_MAX_ROWS = 1024
 
 
 def rhs_width(x: np.ndarray) -> int:
@@ -92,7 +109,8 @@ class ChunkTask:
         speed 1.0 (the engine's calibration knob — real compute below it is
         topped up by sleeping, which is how injected slowdowns throttle
         real work; a B-wide chunk is stretched to B× the matvec time).
-    cancel: master-held event; checked before every chunk.
+    cancel: master-held event; checked, with the shard's presence, once
+        per backend call (before a run of the task's chunks).
     """
 
     round_id: int
@@ -118,8 +136,10 @@ class ChunkDone:
     #                                service time from queue wait
     cost: Tuple[int, float, int, float] = obs.NO_COST  # (calls, wall_s,
     #                                cpu_calls, cpu_s) the backend charged
-    #                                to this chunk (obs.close_record); the
-    #                                round driver sums it into RoundMetrics
+    #                                to the call that computed this chunk
+    #                                (obs.close_record), on the first chunk
+    #                                of the call's run only; summed per
+    #                                round into RoundMetrics
 
 
 @dataclasses.dataclass
@@ -207,18 +227,21 @@ class KernelBackend:
     * each (worker_id, shard_id) shard is converted/uploaded ONCE and kept
       device-resident (float32, the kernel's compute dtype) until the
       tenant is unloaded (``drop_shard``);
-    * the per-chunk operand x is cached in a small LRU (see ``_device_x``)
+    * the operand x is cached in a small LRU (see ``_device_x``)
       so pipelined tenants alternating RHS operands all stay cached at
       once; small operands are content-keyed, large immutable blocks are
       identity-keyed (content-keying an ``(d, B)`` block would cost
-      O(d·B) per chunk);
-    * each chunk is one ``ops.chunk_matvec`` call on the resident shard:
-      the chunk's start row is a traced argument, so one compiled program
-      per ``(shard shape, rows_per_chunk, B)`` serves every chunk, and the
-      chunk is walked in row blocks small enough for the kernel's VMEM
-      whatever its size.  Multi-RHS widths are bucketed to the next power
-      of two (floor 1), so coalesced batch widths land on a handful of
-      kernel shapes instead of retracing for every distinct ``B``.
+      O(d·B) per call);
+    * each call — one chunk, or a worker's run of contiguous chunks of at
+      most :data:`RUN_MAX_ROWS` rows — is one ``ops.chunk_matvec`` call on
+      the resident shard: the start row is a traced argument, so one
+      compiled program per ``(shard shape, rows of the call, B)`` serves
+      every call of that size (a run of 1, 2 or 3 chunks: three
+      programs), and the rows are walked in blocks small enough for the
+      kernel's VMEM whatever their count.  Multi-RHS widths are bucketed
+      to the next power of two (floor 1), so coalesced batch widths land
+      on a handful of kernel shapes instead of retracing for every
+      distinct ``B``.
 
     It computes in the process that holds the accelerator: the engine
     refuses it with a multi-process transport, whose children would each
@@ -246,7 +269,7 @@ class KernelBackend:
         self._shards: "OrderedDict[Tuple[int, str], object]" = OrderedDict()
         # x LRU: one slot per distinct operand, so concurrent rounds
         # alternating RHS operands (pipelined tenants) each keep their
-        # device copy instead of evicting one another on every chunk.
+        # device copy instead of evicting one another on every call.
         # Entries are (weakref-anchor-or-None, device) pairs — see
         # _device_x for the keying scheme and how the weakref keeps
         # identity keys sound without pinning dead rounds' host arrays.
@@ -281,13 +304,13 @@ class KernelBackend:
     def _device_x(self, x: np.ndarray, pad_cols: int = 0):
         """Device copy of the RHS operand, LRU-cached.
 
-        The keying trades per-chunk cost against soundness:
+        The keying trades per-call cost against soundness:
 
         * small operands (≤ ``_X_HASH_CAP`` bytes) are CONTENT-keyed —
           cheap, and in-place mutation between rounds (gradient descent's
           ``w -= ...`` on the same array object) can never serve a stale
           device copy;
-        * a larger block would pay O(d·B) per chunk to content-key, so a
+        * a larger block would pay O(d·B) per call to content-key, so a
           read-only array (the engine marks every round snapshot
           immutable) is keyed by IDENTITY instead.  Sound because the
           entry carries a weakref to the exact array object: while the
@@ -625,7 +648,7 @@ class Worker(threading.Thread):
                     self.idle_s += time.perf_counter() - t_wait
                 if not self._items:
                     return              # stopped and drained
-                tp, chunk_id, r0, r1 = self._items.popleft()
+                tp, run = self._take_run_locked()
                 tp.running = True
                 self._active = tp
                 if not tp.started:
@@ -635,20 +658,65 @@ class Worker(threading.Thread):
                 if self.dead:
                     # fail-stopped: consume silently, forever
                     with self._cv:
-                        tp.remaining -= 1
+                        tp.remaining -= len(run)
                 else:
-                    self._run_item(tp, chunk_id, r0, r1)
+                    self._run_items(tp, run)
             finally:
                 with self._cv:
                     tp.running = False
                     self._active = None
 
-    def _call_backend(self, task: ChunkTask, a: np.ndarray, r0: int,
-                      r1: int) -> np.ndarray:
-        if self._compute_chunk is not None:
-            return self._compute_chunk(self.worker_id, task.shard_id, a,
-                                       r0, r1, task.x)
-        return self.compute(a[r0:r1], task.x)
+    def _take_run_locked(
+            self) -> Tuple[_TaskProgress, List[Tuple[int, int, int]]]:
+        """Pop the head item and the queued items that one backend call
+        can take with it (the caller holds ``_cv``): items of the head's
+        task whose rows continue the run's, up to :data:`RUN_MAX_ROWS` rows
+        and half, rounded up, of the task's queued chunks.  Only a
+        shard-aware backend takes more than the head."""
+        # s2c2lint: ignore[S2C201] _locked-suffix contract: caller holds _cv
+        items = self._items
+        tp, chunk_id, r0, r1 = items.popleft()
+        run = [(chunk_id, r0, r1)]
+        if self._compute_chunk is None:
+            return tp, run
+        # nothing of tp runs while its next item is popped, so remaining
+        # counts exactly its queued chunks, the head included
+        cap = (tp.remaining + 1) // 2
+        while len(run) < cap and items:
+            nxt, chunk_id, n0, n1 = items[0]
+            if nxt is not tp or n0 != r1 or n1 - r0 > RUN_MAX_ROWS:
+                break
+            items.popleft()
+            run.append((chunk_id, n0, n1))
+            r1 = n1
+        return tp, run
+
+    def _call_run(self, task: ChunkTask, a: np.ndarray,
+                  run: List[Tuple[int, int, int]]) -> List[np.ndarray]:
+        """Each chunk's result, in run order: one backend call for the
+        run's rows, sliced per chunk.  A shard-aware backend may answer
+        with a leading part of the rows asked that covers whole chunks (one
+        written for one chunk per call answers with one chunk's): the
+        worker then asks again from the first chunk not covered.  Rows past
+        those asked are not used."""
+        if self._compute_chunk is None:
+            ((_cid, r0, r1),) = run
+            return [self.compute(a[r0:r1], task.x)]
+        parts: List[np.ndarray] = []
+        end = run[-1][2]
+        while len(parts) < len(run):
+            r0 = run[len(parts)][1]
+            y = self._compute_chunk(self.worker_id, task.shard_id, a,
+                                    r0, end, task.x)
+            covered = len(parts)
+            for _cid, c0, c1 in run[covered:]:
+                if c1 - r0 > len(y):
+                    break
+                parts.append(y[c0 - r0:c1 - r0])
+            if len(parts) == covered:
+                raise ValueError(f"backend returned {len(y)} rows for rows "
+                                 f"{r0}:{end}, less than a chunk")
+        return parts
 
     def _purge_task(self, tp: _TaskProgress) -> None:
         """Drop every remaining queued chunk of ``tp`` (cancel/evict/death)."""
@@ -662,9 +730,11 @@ class Worker(threading.Thread):
         with self._cv:
             self._items.clear()
 
-    def _run_item(self, tp: _TaskProgress, chunk_id: int,
-                  r0: int, r1: int) -> None:
+    def _run_items(self, tp: _TaskProgress,
+                   run: List[Tuple[int, int, int]]) -> None:
         task = tp.task
+        head = run[0][0]
+        r0, r1 = run[0][1], run[-1][2]
         with self._shard_lock:
             a = self.shards.get(task.shard_id)
         if task.cancel.is_set() or a is None:
@@ -693,32 +763,41 @@ class Worker(threading.Thread):
             self._drop_everything()
             return
         width = rhs_width(task.x)
-        # the backend call charges this chunk's record; its span (only
-        # while a profiler records or the tracer is on: a span costs the
-        # chunk path a microsecond) is the call alone, with the injected
-        # speed riding along so a slow span is attributable
+        # the backend call charges the run's record; its span (only while
+        # a profiler records or the tracer is on: a span costs the call
+        # path a microsecond) is the call alone, named by the run's first
+        # chunk, with the injected speed riding along so a slow span is
+        # attributable; the tracer gets one chunk record per chunk, each
+        # with the call's stamps
         obs.open_record()
         try:
             if self.tracer.enabled or obs.recording():
-                with obs.span("s2c2.worker.chunk", self.tracer,
-                              obs.KIND_CHUNK, worker=self.worker_id,
-                              round_id=task.round_id, chunk_id=chunk_id,
-                              speed=s, rows=r1 - r0, width=width) as chunk:
-                    y = self._call_backend(task, a, r0, r1)
+                with obs.span("s2c2.worker.chunk", worker=self.worker_id,
+                              round_id=task.round_id,
+                              chunk_id=head) as chunk:
+                    parts = self._call_run(task, a, run)
                 t0, t_call = chunk.t0, chunk.t1
+                if self.tracer.enabled:
+                    for chunk_id, c0, c1 in run:
+                        self.tracer.emit(obs.KIND_CHUNK, worker=self.worker_id,
+                                         round_id=task.round_id,
+                                         chunk_id=chunk_id, t=t0,
+                                         dur=t_call - t0, speed=s,
+                                         rows=c1 - c0, width=width)
             else:
                 t0 = time.perf_counter()
-                y = self._call_backend(task, a, r0, r1)
+                parts = self._call_run(task, a, run)
                 t_call = time.perf_counter()
         except Exception as exc:
             # a backend error is NOT fail-stop silence: report the real
-            # reason terminally, then go dead (every later item is dropped)
+            # reason terminally, then go dead (every later item is dropped;
+            # the master fails over every chunk of the run)
             self.dead = True
             now = time.perf_counter()
             if self.tracer.enabled:
                 self.tracer.emit(obs.KIND_WORKER_FAILED,
                                  worker=self.worker_id,
-                                 round_id=task.round_id, chunk_id=chunk_id,
+                                 round_id=task.round_id, chunk_id=head,
                                  t=now, error=f"{type(exc).__name__}: {exc}")
             self.events.put(WorkerFailed(
                 self.worker_id, task.round_id, now,
@@ -733,16 +812,20 @@ class Worker(threading.Thread):
         if target > t_call - t0:
             with obs.span("s2c2.worker.throttle", self.tracer,
                           obs.KIND_THROTTLE, worker=self.worker_id,
-                          round_id=task.round_id, chunk_id=chunk_id):
+                          round_id=task.round_id, chunk_id=head):
                 time.sleep(target - (t_call - t0))
         t1 = time.perf_counter()
         self.busy_s += t1 - t0
-        self.events.put(ChunkDone(self.worker_id, task.round_id,
-                                  chunk_id, y, t1, t_start=tp.t_start,
-                                  cost=cost))
+        # one result per chunk; the run's cost record rides the first, so
+        # the round counts backend calls
+        for (chunk_id, _c0, _c1), part in zip(run, parts):
+            self.events.put(ChunkDone(self.worker_id, task.round_id,
+                                      chunk_id, part, t1,
+                                      t_start=tp.t_start, cost=cost))
+            cost = obs.NO_COST
         with self._cv:
-            tp.done += 1
-            tp.remaining -= 1
+            tp.done += len(run)
+            tp.remaining -= len(run)
             finished = tp.remaining == 0
         if finished:
             self.events.put(WorkerDone(self.worker_id, task.round_id,

@@ -242,6 +242,18 @@ class _BusyThenBlock:
         return shard[r0:r1] @ x
 
 
+def _runs(chunk_ids):
+    """Backend calls for one task's chunks, queued in this order: each call
+    takes consecutive chunk ids, at most half (rounded up) of those left."""
+    calls = i = 0
+    while i < len(chunk_ids):
+        cap, m = -(-(len(chunk_ids) - i) // 2), 1
+        while m < cap and chunk_ids[i + m] == chunk_ids[i + m - 1] + 1:
+            m += 1
+        calls, i = calls + 1, i + m
+    return calls
+
+
 def _run_worker(compute, chunks=2, rows=8):
     """One Worker computing ``chunks`` chunks; returns its ChunkDones."""
     events = queue.Queue()
@@ -271,13 +283,18 @@ class TestCostRecords:
     def test_busy_then_block_splits_into_cpu_and_off_cpu(self,
                                                           monkeypatch):
         """A thread's first call has its CPU read, and one in CPU_SAMPLE
-        after it; the wall time of every call is read."""
+        after it; the wall time of every call is read.  A call's record
+        rides the first chunk of its run."""
         monkeypatch.setattr(obs, "CPU_SAMPLE", 4)
         done = _run_worker(_BusyThenBlock(cpu_s=0.01, wait_s=0.015),
-                           chunks=6)
+                           chunks=32)
         costs = [obs.CallCost(*ev.cost) for ev in done]
-        assert [c.cpu_calls for c in costs] == [1, 0, 0, 0, 1, 0]
-        for c in costs:
+        # runs of 16, 8, 4, 2, 1 and 1 chunks: half the queue each time
+        heads = [0, 16, 24, 28, 30, 31]
+        assert [i for i, c in enumerate(costs) if c != obs.NO_COST] == heads
+        calls = [costs[i] for i in heads]
+        assert [c.cpu_calls for c in calls] == [1, 0, 0, 0, 1, 0]
+        for c in calls:
             assert c.calls == 1 and 0.025 <= c.wall_s < 1.0
             if c.cpu_calls:
                 assert c.cpu_s >= 0.01
@@ -292,14 +309,16 @@ class TestCostRecords:
     def test_record_excludes_the_wrappers_sleep(self):
         """KernelBackend under the benchmark's TimedBackend, whose mix
         sleeps as long again after each call: every record is at most the
-        TimedBackend call's compute time, and under the whole chunk."""
+        TimedBackend call's compute time, and under the whole call.  Three
+        queued chunks are two calls: a run of two, then one."""
         mix = types.SimpleNamespace(slowdown=lambda worker: 1.0)
         timed = TimedBackend(kernel_backend(), mix)
         done = _run_worker(timed, chunks=3)
         calls = timed.records()
-        assert len(calls) == len(done) == 3
+        assert len(calls) == 2 and len(done) == 3
         costs = [obs.CallCost(*ev.cost) for ev in done]
-        for c, (t0, t1, t2, _w, asked) in zip(costs, calls):
+        assert costs[1] == obs.NO_COST
+        for c, (t0, t1, t2, _w, asked) in zip([costs[0], costs[2]], calls):
             assert c.calls == 1
             assert c.wall_s <= t1 - t0
             assert asked > 0.0 and c.wall_s < t2 - t0
@@ -307,10 +326,16 @@ class TestCostRecords:
         assert 0.0 < costs[0].cpu_s <= costs[0].wall_s
 
     def test_round_sums_the_chunk_results_it_receives(self):
+        """With no steals and no §4.3 waves every dispatched chunk is
+        computed once and received, so the calls the rounds count are
+        exactly the runs of each dispatched task."""
         n, k, d, chunks = 4, 3, 96, 4
         timed = TimedBackend(kernel_backend(), None)
         tr = obs.Tracer()
-        eng = _engine(n, k, timed, tracer=tr)
+        eng = CodedExecutionEngine(
+            ClusterConfig(n_workers=n, k=k, row_cost=1e-5,
+                          enable_stealing=False, max_reassign_waves=0),
+            NoSlowdown(), compute=timed, tracer=tr)
         try:
             a = RNG.standard_normal((d, 32))
             data = eng.load_matrix(a, chunks=chunks)
@@ -321,8 +346,15 @@ class TestCostRecords:
             eng.shutdown()
         reg = eng.registry
         calls = sum(m.chunk_calls for m in ms)
-        assert calls == int(reg.value("s2c2_chunks_done_total")) > 0
-        assert calls <= len(timed.records())
+        tasks = {}
+        for r in tr.snapshot():
+            if r.kind == obs.KIND_ENQUEUE:
+                tasks.setdefault((r.worker, r.round_id, r.t),
+                                 []).append(r.chunk_id)
+        assert calls == sum(_runs(ids) for ids in tasks.values()) > 0
+        assert int(reg.value("s2c2_chunks_done_total")) == \
+            sum(len(ids) for ids in tasks.values()) > calls
+        assert calls == len(timed.records())
         cpu_calls = sum(m.chunk_cpu_calls for m in ms)
         assert 0 < cpu_calls <= calls
         for m in ms:

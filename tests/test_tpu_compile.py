@@ -71,6 +71,11 @@ def _compiled_text(fn, *args) -> str:
     (SHARD_ROWS, CHUNK_ROWS, 16, jnp.float32),     # the smoke's B=16 chunk
     (8192, 8192, None, jnp.float32),               # one 8192-row chunk
     (SHARD_ROWS, CHUNK_ROWS, 16, jnp.bfloat16),    # bf16: default precision
+    # a worker's run of two or three contiguous chunks in one call
+    (SHARD_ROWS, 2 * CHUNK_ROWS, None, jnp.float32),
+    (SHARD_ROWS, 3 * CHUNK_ROWS, None, jnp.float32),
+    (SHARD_ROWS, 2 * CHUNK_ROWS, 16, jnp.float32),
+    (SHARD_ROWS, 3 * CHUNK_ROWS, 16, jnp.float32),
 ])
 def test_chunk_matvec(one_chip, shard_rows, rows, nvec, dtype):
     x_shape = (D,) if nvec is None else (D, nvec)
