@@ -114,7 +114,11 @@ def main() -> int:
                 else:
                     a = rng.standard_normal((480, 12))
                     y = np.sign(a @ rng.standard_normal(12))
-                    svc.submit(RegressionJob(a, y, strat, epochs=3, chunks=8))
+                    # both products of each step, A·w and Aᵀ·r, are coded
+                    # rounds: the job's transpose is installed with it
+                    svc.submit(RegressionJob(
+                        a, y, strat, epochs=3, chunks=8, l2=1e-3,
+                        w0=rng.standard_normal(12) / np.sqrt(12)))
             svc.drain(timeout=300)
             print("\nJobService report (24 heterogeneous jobs, shared-matrix "
                   "tenants coalesced):")

@@ -50,6 +50,13 @@ def _pad_rows(a: np.ndarray, multiple: int) -> np.ndarray:
     return np.concatenate([a, np.zeros((rem,) + a.shape[1:], a.dtype)], axis=0)
 
 
+def _cols(partitions: Sequence[np.ndarray]) -> int:
+    if not partitions:
+        return 0
+    shape = partitions[0].shape
+    return int(shape[1]) if len(shape) > 1 else 1
+
+
 @dataclasses.dataclass
 class CodedData:
     """An (n, k)-MDS encoded, chunk-decomposed tenant matrix."""
@@ -79,6 +86,12 @@ class CodedData:
     @property
     def n(self) -> int:
         return self.code.n
+
+    @property
+    def cols(self) -> int:
+        """Contraction width of the shards (0 for a recovered skeleton,
+        which holds no partitions)."""
+        return _cols(self.partitions)
 
     @property
     def k(self) -> int:
@@ -201,6 +214,11 @@ class ReplicatedData:
         return cls(shard_id=shard_id, n=n, rows_per_part=rpp,
                    orig_rows=orig_rows, placement=np.asarray(placement),
                    partitions=parts)
+
+    @property
+    def cols(self) -> int:
+        """Contraction width of the partitions."""
+        return _cols(self.partitions)
 
     def part_shard_id(self, p: int) -> str:
         return f"{self.shard_id}/p{p}"

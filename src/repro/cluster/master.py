@@ -51,7 +51,9 @@ only (``CodedData.gather_used`` sorts responders), so the two modes decode
 bit-identically whenever coverage matches.
 
 Speed observation closes the paper's §6.2 loop: measured speeds
-(rows · row_cost / response time) feed the shared
+(rows · row_cost / response time, ``row_cost`` scaled by the shard's
+width so that the unit is an element: :meth:`CodedExecutionEngine.
+_row_cost`) feed the shared
 :class:`~repro.core.predictor.SpeedPredictor`, whose predictions feed the
 next round's plan.  A :class:`~repro.runtime.elastic.FailureDetector`
 accumulates timeout strikes and declares fail-stopped workers dead, which
@@ -208,7 +210,10 @@ class RoundHandle:
 class _RoundState:
     """Mutable collection state of one in-flight round."""
 
-    def __init__(self, n: int, k: int, chunks: int):
+    def __init__(self, n: int, k: int, chunks: int, row_cost: float):
+        # virtual seconds of one row of the round's shard per RHS column
+        # at speed 1 (CodedExecutionEngine._row_cost)
+        self.row_cost = row_cost
         self.covered_by: List[Set[int]] = [set() for _ in range(chunks)]
         self.used: List[List[int]] = [[] for _ in range(chunks)]
         self.partials: Dict[Tuple[int, int], np.ndarray] = {}
@@ -273,6 +278,14 @@ class EngineClosed(RuntimeError):
 class _EngineClosedSentinel:
     """Dropped into every live round inbox by ``shutdown()``: the round
     driver raises :class:`EngineClosed` into its handle and exits."""
+
+
+class _Transpose:
+    """One tenant's coded transpose, installed once (load_transpose)."""
+
+    def __init__(self):
+        self.lock = threading.Lock()
+        self.data = None                # guarded_by: lock
 
 
 class CodedExecutionEngine:
@@ -363,7 +376,11 @@ class CodedExecutionEngine:
         self.iteration = 0                  # guarded_by: _obs_lock
         self._round_seq = 0                 # guarded_by: _lock
         self._tenant_seq = 0                # guarded_by: _lock
-        self._lock = threading.Lock()       # seq counters only
+        # width of the first matrix loaded: the unit of _row_cost
+        self._unit_cols = 0                 # guarded_by: _lock
+        # source shard id -> its coded transpose (load_transpose)
+        self._transposes: Dict[str, "_Transpose"] = {}  # guarded_by: _lock
+        self._lock = threading.Lock()       # seq counters and the above
         self._obs_lock = threading.Lock()   # predictor/detector/iteration
         # guarded_by: _obs_lock
         self._last_observed: Optional[np.ndarray] = None
@@ -424,6 +441,9 @@ class CodedExecutionEngine:
         self._m_batched = reg.counter(
             "s2c2_batched_rounds_total", "rounds executed with RHS "
             "width > 1")
+        self._m_transposes = reg.counter(
+            "s2c2_transpose_installs_total",
+            "coded transposes encoded and installed (load_transpose)")
         # host cost of the rounds (RoundMetrics.chunk_* / host_cpu_s)
         self._m_backend_calls = reg.counter(
             "s2c2_backend_calls_total",
@@ -636,6 +656,7 @@ class CodedExecutionEngine:
         code = code or MDSCode(self.cfg.n_workers, self.cfg.k,
                                self.cfg.generator_kind)
         data = CodedData.encode(shard_id, a, code, chunks)
+        self._note_width(data)
         for w, worker in enumerate(self.workers):
             worker.install_shard(shard_id, data.partitions[w])
         if self.journal is not None:
@@ -664,13 +685,83 @@ class CodedExecutionEngine:
             shard_id = f"t{self._tenant_seq}"
         data = ReplicatedData.partition(shard_id, a, self.cfg.n_workers,
                                         placement)
+        self._note_width(data)
         for p in range(len(data.partitions)):
             for holder in data.placement[p]:
                 self.workers[int(holder)].install_shard(
                     data.part_shard_id(p), data.partitions[p])
         return data
 
+    def load_transpose(self, data, a: np.ndarray):
+        """The coded transpose of ``a``, the matrix ``data`` holds.
+
+        A second tenant with ``data``'s layout (its code and chunk count,
+        or its replica placement), encoded and installed on the first
+        request and handed to every later one, however many ask at once;
+        :meth:`unload` of ``data`` unloads it too.  With a shard-aware
+        backend every call a run of its chunks can make is compiled here
+        (:meth:`_warm_runs`), so that none compiles inside a round.
+        """
+        a = np.asarray(a, dtype=np.float64)
+        if a.ndim != 2 or a.shape[0] != data.orig_rows:
+            raise ValueError(f"load_transpose: a {a.shape} is not the "
+                             f"matrix of tenant {data.shard_id} "
+                             f"({data.orig_rows} rows)")
+        with self._lock:
+            entry = self._transposes.get(data.shard_id)
+            if entry is None:
+                entry = self._transposes[data.shard_id] = _Transpose()
+        with entry.lock:
+            if entry.data is None:
+                with obs.span("s2c2.engine.transpose_install"):
+                    at = np.ascontiguousarray(a.T)
+                    if isinstance(data, ReplicatedData):
+                        entry.data = self.load_replicated(at, data.placement)
+                    else:
+                        entry.data = self.load_matrix(at, chunks=data.chunks,
+                                                      code=data.code)
+                        self._warm_runs(entry.data)
+                self._m_transposes.inc()
+            return entry.data
+
+    def _warm_runs(self, data: CodedData) -> None:
+        """Make every backend call a run of ``data``'s chunks can take,
+        once, with worker 0's shard: the compiled programs are the
+        backend's, shared by all workers (a remote worker has none)."""
+        warm = getattr(self.workers[0], "warm_runs", None)
+        if warm is not None:
+            x = np.zeros(data.cols)
+            x.setflags(write=False)
+            warm(data.shard_id, data.rows_per_chunk, data.chunks, x)
+
+    def _note_width(self, data) -> None:
+        with self._lock:
+            if not self._unit_cols:
+                self._unit_cols = data.cols
+
+    def _row_cost(self, data) -> float:
+        """Virtual seconds of one row of ``data``'s shard per RHS column at
+        speed 1: ``row_cost`` scaled by the shard's width over the width of
+        the first matrix the engine loaded.  A chunk's work is so counted
+        in elements, in planning, deadlines, measured speeds and the
+        worker's top-up alike: a speed learned on one matrix plans another
+        of a different width, and an engine of one width plans as if
+        ``row_cost`` were per row."""
+        with self._lock:
+            unit = self._unit_cols
+        cols = data.cols
+        if not unit or not cols:
+            return self.cfg.row_cost
+        return self.cfg.row_cost * cols / unit
+
     def unload(self, data) -> None:
+        with self._lock:
+            entry = self._transposes.pop(data.shard_id, None)
+        if entry is not None:
+            with entry.lock:
+                twin, entry.data = entry.data, None
+            if twin is not None:
+                self.unload(twin)
         if isinstance(data, ReplicatedData):
             for p in range(len(data.partitions)):
                 for holder in data.placement[p]:
@@ -1035,17 +1126,17 @@ class CodedExecutionEngine:
     # coded path (MDSCoded / BasicS2C2 / GeneralS2C2)
     # ------------------------------------------------------------------
 
-    def _plan(self, data: CodedData, strategy,
-              width: int = 1) -> Tuple[Allocation, float]:
+    def _plan(self, data: CodedData, strategy, width: int,
+              row_cost: float) -> Tuple[Allocation, float]:
         """Allocation + planned (virtual-seconds) makespan for this round.
 
         ``width`` is the round's RHS width: a B-wide chunk is B× the
         virtual work (the workers stretch it accordingly), so every
         planned-makespan estimate — and with it the §4.3 deadline clock —
-        scales by B.
+        scales by B.  ``row_cost`` is the shard's (:meth:`_row_cost`).
         """
         n, k, C = data.n, data.k, data.chunks
-        row_cost = self.cfg.row_cost * width
+        row_cost = row_cost * width
         pred = self.predicted_speeds()
         if isinstance(strategy, MDSCoded):
             count = np.full(n, C, dtype=np.int64)
@@ -1084,7 +1175,7 @@ class CodedExecutionEngine:
         task = ChunkTask(
             round_id=rid, iteration=iteration, shard_id=data.shard_id,
             chunks=[(c, *data.chunk_range(c)) for c in chunk_ids],
-            x=x, row_cost=self.cfg.row_cost, cancel=threading.Event())
+            x=x, row_cost=state.row_cost, cancel=threading.Event())
         state.tasks[worker] = task
         state.finish_t[worker] = np.inf
         now = time.perf_counter()
@@ -1109,11 +1200,12 @@ class CodedExecutionEngine:
         # every per-chunk work estimate this round scales by the RHS width:
         # the workers stretch B-wide chunks to B× the virtual time, so the
         # deadline clock, measured speeds, and row accounting must follow
-        work_per_chunk = rpc * width * cfg.row_cost
+        row_cost = self._row_cost(data)
+        work_per_chunk = rpc * width * row_cost
         cpu0 = time.thread_time()
         with obs.span("s2c2.round.plan", self.tracer, obs.KIND_ROUND_PLAN,
                       round_id=rid, strategy=type(strategy).__name__):
-            alloc, planned = self._plan(data, strategy, width)
+            alloc, planned = self._plan(data, strategy, width, row_cost)
             slack = getattr(strategy, "timeout_slack", cfg.timeout_slack)
             # snapshot the injector step under the observation lock (concurrent
             # round drivers bump it in _observe): every dispatch this round —
@@ -1121,7 +1213,7 @@ class CodedExecutionEngine:
             with self._obs_lock:
                 iteration = self.iteration
 
-            state = _RoundState(n, k, C)
+            state = _RoundState(n, k, C, row_cost)
             if seed_acks:
                 # recovery: journaled chunk credits become coverage BEFORE any
                 # dispatch — these chunks are never recomputed
@@ -1511,7 +1603,7 @@ class CodedExecutionEngine:
             partition_credits=state.partition_credits,
             chunk_calls=cost.calls, chunk_wall_s=cost.wall_s,
             chunk_cpu_calls=cost.cpu_calls, chunk_cpu_s=cost.cpu_s,
-            host_cpu_s=host_cpu_s)
+            host_cpu_s=host_cpu_s, cols=data.cols, rows_per_chunk=rpc)
         self._publish_round(metrics, state.chunks_done)
         if self.journal is not None:
             self._journal("retire", {"rid": rid})
@@ -1586,7 +1678,7 @@ class CodedExecutionEngine:
                 # FIFO instead of queueing behind other tenants
                 self.workers[w].promote_round(rid)
                 max_extra = max(max_extra, len(ids))
-        row_cost = self.cfg.row_cost * rhs_width(x)
+        row_cost = state.row_cost * rhs_width(x)
         planned_extra = max_extra * data.rows_per_chunk * row_cost
         if short:
             planned_extra = max(planned_extra,
@@ -1784,7 +1876,8 @@ class CodedExecutionEngine:
                       strategy=type(strategy).__name__) as dispatch:
             rpp = data.rows_per_part
             width = rhs_width(x)            # replicated rounds are width-generic
-            work_per_part = rpp * width * cfg.row_cost
+            row_cost = self._row_cost(data)
+            work_per_part = rpp * width * row_cost
 
             results: List[Optional[np.ndarray]] = [None] * n_parts
             attempt_owner: Dict[int, List[int]] = {p: [] for p in range(n_parts)}
@@ -1798,7 +1891,7 @@ class CodedExecutionEngine:
                 task = ChunkTask(round_id=rid, iteration=iteration,
                                  shard_id=data.part_shard_id(p),
                                  chunks=[(p, 0, rpp)], x=x,
-                                 row_cost=cfg.row_cost, cancel=threading.Event())
+                                 row_cost=row_cost, cancel=threading.Event())
                 tasks[(p, w)] = task
                 attempt_owner[p].append(w)
                 busy.add(w)
@@ -1952,7 +2045,7 @@ class CodedExecutionEngine:
                 # so fall back to collection end as the response time
                 el = max((finish_t[w] if np.isfinite(finish_t[w])
                           else t_collected) - t0, 1e-9)
-                speeds[w] = rows_done[w] * width * cfg.row_cost / el
+                speeds[w] = rows_done[w] * width * row_cost / el
                 response[w] = el
             elif self._worker_last_event[w] >= t0:
                 continue    # alive on other rounds: no measurement/strike
@@ -1978,6 +2071,6 @@ class CodedExecutionEngine:
             inflight=inflight, rhs_width=width,
             chunk_calls=cost.calls, chunk_wall_s=cost.wall_s,
             chunk_cpu_calls=cost.cpu_calls, chunk_cpu_s=cost.cpu_s,
-            host_cpu_s=host_cpu_s)
+            host_cpu_s=host_cpu_s, cols=data.cols, rows_per_chunk=rpp)
         self._publish_round(metrics)
         return RoundOutput(y=y, metrics=metrics)

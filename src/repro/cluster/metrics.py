@@ -35,7 +35,8 @@ class RoundMetrics:
     useful_rows: np.ndarray           # (n,) row-equivalents used in the
     #                                   decode (rows × RHS width)
     wasted_rows: np.ndarray           # (n,) row-equivalents computed unused
-    speeds_measured: np.ndarray       # (n,) rows/s · row_cost (1.0 = nominal)
+    speeds_measured: np.ndarray       # (n,) rows/s · the shard's row cost
+    #                                   (1.0 = nominal)
     planned_makespan: float           # master's own prediction (virtual s)
     reassign_waves: int = 0
     mispredicted: bool = False
@@ -66,6 +67,10 @@ class RoundMetrics:
     chunk_cpu_s: float = 0.0          # their calling threads' CPU seconds
     host_cpu_s: float = 0.0           # round driver's thread CPU seconds,
     #                                   plan start to decode end
+    cols: int = 0                     # contraction width of the round's
+    #                                   shard (0: not known)
+    rows_per_chunk: int = 0           # rows of one chunk (a replicated
+    #                                   round: of one partition)
 
     @property
     def total_useful(self) -> float:
@@ -101,6 +106,9 @@ class JobMetrics:
     t_done: float = 0.0
     rounds: List[RoundMetrics] = dataclasses.field(default_factory=list)
     error: Optional[str] = None
+    gd_step_s: float = 0.0            # host step of a gradient-descent job
+    #                                   (span ``s2c2.job.gd_step``), summed
+    gd_steps: int = 0                 # iterations those steps belong to
 
     @property
     def queue_wait(self) -> float:

@@ -32,8 +32,9 @@ Job kinds (the §6.3 workloads):
 * :class:`MatvecJob`    — a batch of raw coded matvecs against one matrix
   (optionally ``batch``-ed into multi-RHS rounds by the job itself);
 * :class:`PageRankJob`  — damped power iterations (x drifts every round);
-* :class:`RegressionJob`— coded-gradient-descent epochs for logistic / SVM
-  losses (the Ax product is the coded part, as in the paper).
+* :class:`RegressionJob`— full-batch gradient descent for logistic / SVM
+  losses: both products of an iteration, ``A·w`` and ``Aᵀ·r``, are coded
+  rounds, as in the paper.
 """
 
 from __future__ import annotations
@@ -244,6 +245,14 @@ class _CoalescingEngine:
             return self._coalescer.matvec(data, x, strategy)
         return self._engine.matvec(data, x, strategy)
 
+    def load_transpose(self, data, a: np.ndarray):
+        """The engine's transpose of ``data``; a shared matrix's is shared
+        too, so its rounds coalesce like the matrix's own."""
+        twin = self._engine.load_transpose(data, a)
+        if data.shard_id in self._shared_ids:
+            self._shared_ids.add(twin.shard_id)
+        return twin
+
     def __getattr__(self, name):
         return getattr(self._engine, name)
 
@@ -279,8 +288,10 @@ class Job:
             return engine.load_replicated(self.a, placement)
         return engine.load_matrix(self.a, chunks=self.chunks)
 
-    def rounds(self, engine: CodedExecutionEngine, data, record):
-        """Run all rounds; ``record(metrics)`` after each. Returns output."""
+    def rounds(self, engine: CodedExecutionEngine, data,
+               metrics: JobMetrics):
+        """Run all rounds, appending each one's :class:`RoundMetrics` to
+        ``metrics.rounds``.  Returns the output."""
         raise NotImplementedError
 
 
@@ -304,18 +315,18 @@ class MatvecJob(Job):
             raise ValueError(f"batch must be >= 1, got {batch}")
         self.batch = batch
 
-    def rounds(self, engine, data, record):
+    def rounds(self, engine, data, metrics):
         outs = []
         for i in range(0, len(self.xs), self.batch):
             grp = self.xs[i:i + self.batch]
             if len(grp) == 1:
                 out = engine.matvec(data, grp[0], self.strategy)
-                record(out.metrics)
+                metrics.rounds.append(out.metrics)
                 outs.append(out.y)
             else:
                 out = engine.matmul(data, np.stack(grp, axis=1),
                                     self.strategy)
-                record(out.metrics)
+                metrics.rounds.append(out.metrics)
                 outs.extend(np.ascontiguousarray(out.y[:, j])
                             for j in range(len(grp)))
         return np.stack(outs)
@@ -332,43 +343,75 @@ class PageRankJob(Job):
         self.iters = iters
         self.damping = damping
 
-    def rounds(self, engine, data, record):
+    def rounds(self, engine, data, metrics):
         n = self.a.shape[0]
         r = np.ones(n) / n
         for _ in range(self.iters):
             out = engine.matvec(data, r, self.strategy)
-            record(out.metrics)
+            metrics.rounds.append(out.metrics)
             r = (1.0 - self.damping) / n + self.damping * out.y[:n]
         return r
 
 
 class RegressionJob(Job):
-    """Coded gradient descent: the Ax matvec runs on the cluster."""
+    """Full-batch gradient descent with both products coded.
+
+    Each of ``epochs`` iterations is two dependent rounds: ``A·w`` on the
+    job's matrix, then ``Aᵀ·r`` on its transpose, which the engine encodes
+    with the matrix's layout and installs once per matrix
+    (:meth:`~repro.cluster.master.CodedExecutionEngine.load_transpose`).
+    Between them, and after the second, the host step runs in float64:
+    the residual ``r = −y ⊙ σ(−y ⊙ Aw)`` (logistic) or ``−y ⊙ [y ⊙ Aw <
+    1]`` (hinge), then ``w ← w − lr·(Aᵀr/m + l2·w)`` for ``m`` rows (the
+    hinge loss adds its fixed ``1e-3·w/m``).  Starts from ``w0`` (zeros by
+    default).
+    """
 
     kind = "regression"
 
     def __init__(self, a, y, strategy, epochs: int = 5, loss: str = "logistic",
                  lr: float = 0.5, chunks: int = 20,
-                 data: Optional[CodedData] = None):
+                 data: Optional[CodedData] = None,
+                 w0: Optional[np.ndarray] = None, l2: float = 0.0):
         super().__init__(a, strategy, chunks, data=data)
         self.y = np.asarray(y, dtype=np.float64)
         self.epochs = epochs
         self.loss = loss
         self.lr = lr
+        d = self.a.shape[1]
+        self.w0 = (np.zeros(d) if w0 is None
+                   else np.array(w0, dtype=np.float64))
+        if self.w0.shape != (d,):
+            raise ValueError(f"w0 has shape {self.w0.shape}; the matrix "
+                             f"has {d} columns")
+        self.l2 = float(l2)
 
-    def rounds(self, engine, data, record):
+    def rounds(self, engine, data, metrics):
         a, yv = self.a, self.y
-        w = np.zeros(a.shape[1])
+        m, d = a.shape
+        at = engine.load_transpose(data, a)
+        strategy_t = dataclasses.replace(self.strategy, total_rows=d)
+        w = self.w0.copy()
         for _ in range(self.epochs):
             out = engine.matvec(data, w, self.strategy)
-            record(out.metrics)
-            ax = out.y[: a.shape[0]]
-            margin = yv * ax
-            if self.loss == "logistic":
-                g = a.T @ (-yv / (1.0 + np.exp(margin)))
-            else:                                   # hinge (SVM)
-                g = a.T @ (-yv * (margin < 1)) + 1e-3 * w
-            w -= (self.lr / a.shape[0]) * g
+            metrics.rounds.append(out.metrics)
+            with obs.span("s2c2.job.gd_step", job=metrics.job_id) as step:
+                margin = yv * out.y[:m]
+                if self.loss == "logistic":
+                    # −y·σ(−margin), without overflow for large margins
+                    r = -yv * np.exp(-np.logaddexp(0.0, margin))
+                else:                               # hinge (SVM)
+                    r = -yv * (margin < 1)
+            metrics.gd_step_s += step.dur
+            out = engine.matvec(at, r, strategy_t)
+            metrics.rounds.append(out.metrics)
+            with obs.span("s2c2.job.gd_step", job=metrics.job_id) as step:
+                g = out.y[:d] / m + self.l2 * w
+                if self.loss != "logistic":
+                    g += 1e-3 * w / m
+                w = w - self.lr * g
+            metrics.gd_step_s += step.dur
+            metrics.gd_steps += 1
         return w
 
 
@@ -401,6 +444,8 @@ def _job_spec(job: Job) -> Optional[Dict]:
         spec["epochs"] = job.epochs
         spec["loss"] = job.loss
         spec["lr"] = job.lr
+        spec["w0"] = encode_array(job.w0)
+        spec["l2"] = job.l2
     else:
         return None                       # unknown subclass: can't rebuild
     return spec
@@ -426,7 +471,10 @@ def _job_from_spec(rec: Dict) -> Optional[Job]:
         if kind == "regression":
             return RegressionJob(a, decode_array(spec["y"]), strategy,
                                  epochs=spec["epochs"], loss=spec["loss"],
-                                 lr=spec["lr"], chunks=spec["chunks"])
+                                 lr=spec["lr"], chunks=spec["chunks"],
+                                 w0=(decode_array(spec["w0"])
+                                     if "w0" in spec else None),
+                                 l2=spec.get("l2", 0.0))
     except Exception as exc:
         logger.warning("journal: admit record not rebuildable: %s", exc)
     return None
@@ -734,8 +782,7 @@ class JobService:
                 try:
                     data = handle.job.prepare(self.engine)
                     owned = handle.job.data is None     # shared data outlives jobs
-                    handle.output = handle.job.rounds(
-                        self._exec, data, m.rounds.append)
+                    handle.output = handle.job.rounds(self._exec, data, m)
                 except Exception as exc:          # record, don't kill the service
                     m.error = f"{type(exc).__name__}: {exc}"
                     engine_closed = isinstance(exc, EngineClosed)

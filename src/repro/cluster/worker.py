@@ -14,9 +14,13 @@ A shard-aware backend (below) is handed a *run* of queued chunks in one
 call: the head item and the items behind it of the same task whose rows
 continue it, up to :data:`RUN_MAX_ROWS` rows and at most half (rounded
 up) of the task's queued chunks, so a backlog stays behind the run for
-retraction and the steal pass.  The call's result is split back into one
-:class:`ChunkDone` per chunk.  A plain ``compute(a_rows, x)`` backend has
-no per-call cost worth sharing and keeps one call per chunk.
+retraction and the steal pass.  A run's length is then rounded down to
+the ladder 1, 2, 3, 4, 6, 8, 12, … (:func:`run_lengths`), so that a
+backend that compiles one program per row count of a call meets a few
+row counts per shard, all of which :meth:`Worker.warm_runs` can make
+ahead.  The call's result is split back into one :class:`ChunkDone` per
+chunk.  A plain ``compute(a_rows, x)`` backend has no per-call cost worth
+sharing and keeps one call per chunk.
 
 The inbox is chunk-granular on purpose (the work-stealing substrate): a
 dispatched :class:`ChunkTask` is split into one queue item per chunk, and
@@ -33,7 +37,8 @@ the master may
 
 Speed injection: before each call the worker asks its injector for the
 current speed ``s`` and stretches the call to ``rows · B · row_cost / s``
-seconds of wall time, where ``B`` is the RHS width (compute runs natively;
+seconds of wall time, where ``B`` is the RHS width and ``row_cost`` the
+task's, which the master scales by the shard's width (compute runs natively;
 the remainder is slept, so the throttling is real wall-clock, not
 bookkeeping).  A multi-RHS chunk does ``B×`` the work of a matvec chunk,
 so it must pay ``B×`` the virtual time — otherwise injector-driven
@@ -78,7 +83,8 @@ from repro.cluster.obs import NULL_TRACER, Tracer
 
 __all__ = ["ChunkTask", "ChunkDone", "WorkerDone", "WorkerFailed",
            "WorkerRejoined", "Worker", "numpy_backend", "kernel_backend",
-           "KernelBackend", "rhs_width", "shard_digest", "RUN_MAX_ROWS"]
+           "KernelBackend", "rhs_width", "run_lengths", "shard_digest",
+           "RUN_MAX_ROWS"]
 
 logger = logging.getLogger("repro.cluster.worker")
 
@@ -90,6 +96,25 @@ ComputeFn = Callable[[np.ndarray, np.ndarray], np.ndarray]
 #: is one kernel block with no padded rows; chunks of more than half of
 #: it run one per call.
 RUN_MAX_ROWS = 1024
+
+
+def _on_ladder(length: int) -> bool:
+    """Whether ``length`` is 2^j or 3·2^j."""
+    odd = length
+    while odd % 2 == 0:
+        odd //= 2
+    return odd in (1, 3)
+
+
+def run_lengths(rows_per_chunk: int, chunks: int) -> List[int]:
+    """Every number of chunks one backend call can take of a shard of
+    ``chunks`` chunks of ``rows_per_chunk`` rows: the ladder 1, 2, 3, 4, 6,
+    8, 12, … up to the longest run, half (rounded up) of a task of every
+    chunk and at most :data:`RUN_MAX_ROWS` rows.  Runs of 3 or fewer
+    chunks keep their greedy length; a longer one is rounded down at most
+    by a third."""
+    longest = min((chunks + 1) // 2, max(RUN_MAX_ROWS // rows_per_chunk, 1))
+    return [n for n in range(1, longest + 1) if _on_ladder(n)]
 
 
 def rhs_width(x: np.ndarray) -> int:
@@ -105,10 +130,11 @@ class ChunkTask:
     x: the round's RHS operand — a ``(d,)`` vector (matvec round) or an
         ``(d, B)`` multi-RHS block (batched round); each chunk then yields
         a ``(rows,)`` or ``(rows, B)`` partial.
-    row_cost: seconds of *virtual* wall time per row PER RHS COLUMN at
-        speed 1.0 (the engine's calibration knob — real compute below it is
-        topped up by sleeping, which is how injected slowdowns throttle
-        real work; a B-wide chunk is stretched to B× the matvec time).
+    row_cost: seconds of *virtual* wall time per row of this shard PER
+        RHS COLUMN at speed 1.0 (the engine's calibration knob scaled by
+        the shard's width — real compute below it is topped up by
+        sleeping, which is how injected slowdowns throttle real work; a
+        B-wide chunk is stretched to B× the matvec time).
     cancel: master-held event; checked, with the shard's presence, once
         per backend call (before a run of the task's chunks).
     """
@@ -236,8 +262,9 @@ class KernelBackend:
       most :data:`RUN_MAX_ROWS` rows — is one ``ops.chunk_matvec`` call on
       the resident shard: the start row is a traced argument, so one
       compiled program per ``(shard shape, rows of the call, B)`` serves
-      every call of that size (a run of 1, 2 or 3 chunks: three
-      programs), and the rows are walked in blocks small enough for the
+      every call of that size (the run lengths of
+      :func:`run_lengths`: three programs at 328 rows a chunk, six at
+      25), and the rows are walked in blocks small enough for the
       kernel's VMEM whatever their count.  Multi-RHS widths are bucketed
       to the next power of two (floor 1), so coalesced batch widths land
       on a handful of kernel shapes instead of retracing for every
@@ -689,7 +716,29 @@ class Worker(threading.Thread):
             items.popleft()
             run.append((chunk_id, n0, n1))
             r1 = n1
+        # down to the ladder of run_lengths; the rest goes back in order
+        while not _on_ladder(len(run)):
+            chunk_id, n0, n1 = run.pop()
+            items.appendleft((tp, chunk_id, n0, n1))
         return tp, run
+
+    def warm_runs(self, shard_id: str, rows_per_chunk: int, chunks: int,
+                  x: np.ndarray) -> int:
+        """Make, in the calling thread, one backend call of each length
+        of :func:`run_lengths` from the top of installed shard
+        ``shard_id`` against ``x``, so that a backend that compiles per
+        row count has compiled every call a round of this shard and this
+        operand shape can make.  Returns the calls made: none without a
+        shard-aware backend."""
+        if self._compute_chunk is None:
+            return 0
+        with self._shard_lock:
+            a = self.shards[shard_id]
+        lengths = run_lengths(rows_per_chunk, chunks)
+        for n in lengths:
+            self._compute_chunk(self.worker_id, shard_id, a, 0,
+                                n * rows_per_chunk, x)
+        return len(lengths)
 
     def _call_run(self, task: ChunkTask, a: np.ndarray,
                   run: List[Tuple[int, int, int]]) -> List[np.ndarray]:

@@ -84,7 +84,9 @@ def make_lr_dataset(rows: int = 20000, cols: int = 500, seed: int = 0,
 
     The paper duplicates the UCI gisette dataset (5000 features) to scale
     it; we synthesize an equivalent dense matrix with a planted separator
-    so convergence is measurable.
+    so convergence is measurable.  The benchmark's configuration
+    ``gd-gisette-96000x5000-c12k10`` draws the same recipe at 96000 ×
+    5000, 16 Gisettes, in ``bench/matrices/planted_logistic.py``.
     """
     rng = np.random.default_rng(seed)
     a = rng.standard_normal((rows, cols))

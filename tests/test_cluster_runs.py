@@ -3,8 +3,9 @@
 A :class:`~repro.cluster.worker.Worker` with a shard-aware backend hands
 it the head of its queue together with the queued chunks of the same task
 whose rows continue it — at most ``RUN_MAX_ROWS`` rows and half, rounded
-up, of the task's queued chunks — and splits the call's result back into
-one :class:`ChunkDone` per chunk.  A plain ``compute(a_rows, x)`` backend
+up, of the task's queued chunks, rounded down to the ladder 1, 2, 3, 4,
+6, 8, … that ``Worker.warm_runs`` compiles ahead — and splits the call's
+result back into one :class:`ChunkDone` per chunk.  A plain ``compute(a_rows, x)`` backend
 keeps one call per chunk.
 """
 
@@ -18,7 +19,7 @@ from repro.cluster import (ClusterConfig, CodedExecutionEngine, NoSlowdown,
                            Worker, kernel_backend)
 from repro.cluster import obs
 from repro.cluster.worker import (RUN_MAX_ROWS, ChunkDone, ChunkTask,
-                                  WorkerDone, WorkerFailed)
+                                  WorkerDone, WorkerFailed, run_lengths)
 from repro.core.strategies import GeneralS2C2
 
 RNG = np.random.default_rng(15)
@@ -113,7 +114,8 @@ class TestRunBoundaries:
 
     @pytest.mark.parametrize("n,want", [
         (1, [1]), (2, [1, 1]), (3, [2, 1]), (8, [4, 2, 1, 1]),
-        (17, [9, 4, 2, 1, 1])])
+        # longer runs are rounded down to the ladder 4, 6, 8, 12, …
+        (11, [6, 3, 1, 1]), (15, [8, 4, 2, 1]), (17, [8, 4, 3, 1, 1])])
     def test_run_takes_at_most_half_of_the_queued_chunks(self, n, want):
         rec = _Recorder()
         _run(rec, [_task(_even(n, 8))], 8 * n)
@@ -336,3 +338,32 @@ class TestRunSemantics:
             np.testing.assert_allclose(out2.y, a @ x, rtol=1e-9, atol=1e-9)
         finally:
             eng.shutdown()
+
+
+@pytest.mark.parametrize("rows,chunks,want", [
+    (328, 20, [1, 2, 3]),               # 65536 x 8192 on (12, 10): as before
+    (480, 20, [1, 2]),
+    (25, 20, [1, 2, 3, 4, 6, 8]),       # the transpose of 96000 x 5000
+    (2000, 20, [1]),
+    (8, 40, [1, 2, 3, 4, 6, 8, 12, 16])])
+def test_run_lengths_are_the_ladder(rows, chunks, want):
+    assert run_lengths(rows, chunks) == want
+
+
+def test_warm_runs_makes_every_call_a_round_can():
+    """``warm_runs`` makes one call of each run length; a task of every
+    chunk then calls with no row count the warm-up did not make."""
+    rec = _Recorder()
+    w = Worker(0, queue.Queue(), NoSlowdown(), compute=rec)
+    w.install_shard("s", RNG.standard_normal((17 * 8, 4)))
+    x = RNG.standard_normal(4)
+    lengths = run_lengths(8, 17)
+    assert w.warm_runs("s", 8, 17, x) == len(lengths)
+    assert rec.calls == [(0, 8 * n) for n in lengths]
+    warmed = {r1 - r0 for r0, r1 in rec.calls}
+    rec = _Recorder()
+    _run(rec, [_task(_even(17, 8))], 17 * 8)
+    assert {r1 - r0 for r0, r1 in rec.calls} <= warmed
+    plain = Worker(1, queue.Queue(), NoSlowdown())
+    plain.install_shard("s", np.ones((8, 4)))
+    assert plain.warm_runs("s", 8, 1, x) == 0

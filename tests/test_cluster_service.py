@@ -88,7 +88,11 @@ class TestServiceThroughput:
 
             rep = svc.report()
             assert rep.n_jobs == n_jobs
-            assert rep.n_rounds == n_jobs * 3        # 3 rounds per job kind
+            # 3 rounds a matvec or PageRank job; a regression job's 3
+            # epochs are 6 (A·w and Aᵀ·r each)
+            n_regression = sum(1 for i in range(n_jobs) if i % 3 == 2)
+            assert rep.n_rounds == (n_jobs - n_regression) * 3 \
+                + n_regression * 6
             assert rep.jobs_per_s > 0
             assert np.isfinite(rep.p50_latency) and np.isfinite(rep.p99_latency)
             assert rep.p99_latency >= rep.p50_latency > 0
