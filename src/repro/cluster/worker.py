@@ -12,13 +12,12 @@ two calls.
 
 A shard-aware backend (below) is handed a *run* of queued chunks in one
 call: the head item and the items behind it of the same task whose rows
-continue it, up to :data:`RUN_MAX_ROWS` rows and at most half (rounded
-up) of the task's queued chunks, so a backlog stays behind the run for
-retraction and the steal pass.  A run's length is then rounded down to
-the ladder 1, 2, 3, 4, 6, 8, 12, … (:func:`run_lengths`), so that a
-backend that compiles one program per row count of a call meets a few
-row counts per shard, all of which :meth:`Worker.warm_runs` can make
-ahead.  The call's result is split back into one :class:`ChunkDone` per
+continue it, at most half (rounded up) of the task's queued chunks, so a
+backlog stays behind the run for retraction and the steal pass.  A run's
+length is then rounded down to the ladder 1, 2, 3, 4, 6, 8, 12, …
+(:func:`run_lengths`), so that a backend that compiles one program per
+row count of a call meets a few row counts per shard, all of which
+:meth:`Worker.warm_runs` can make ahead.  The call's result is split back into one :class:`ChunkDone` per
 chunk.  A plain ``compute(a_rows, x)`` backend has no per-call cost worth
 sharing and keeps one call per chunk.
 
@@ -83,19 +82,11 @@ from repro.cluster.obs import NULL_TRACER, Tracer
 
 __all__ = ["ChunkTask", "ChunkDone", "WorkerDone", "WorkerFailed",
            "WorkerRejoined", "Worker", "numpy_backend", "kernel_backend",
-           "KernelBackend", "rhs_width", "run_lengths", "shard_digest",
-           "RUN_MAX_ROWS"]
+           "KernelBackend", "rhs_width", "run_lengths", "shard_digest"]
 
 logger = logging.getLogger("repro.cluster.worker")
 
 ComputeFn = Callable[[np.ndarray, np.ndarray], np.ndarray]
-
-#: Most rows one call of a shard-aware backend takes for a run of chunks:
-#: the kernel's one-block limit, ``repro.kernels.ops.MAX_BLOCK_ROWS``
-#: (kept here so that this module never imports JAX).  A run of that size
-#: is one kernel block with no padded rows; chunks of more than half of
-#: it run one per call.
-RUN_MAX_ROWS = 1024
 
 
 def _on_ladder(length: int) -> bool:
@@ -106,14 +97,13 @@ def _on_ladder(length: int) -> bool:
     return odd in (1, 3)
 
 
-def run_lengths(rows_per_chunk: int, chunks: int) -> List[int]:
+def run_lengths(chunks: int) -> List[int]:
     """Every number of chunks one backend call can take of a shard of
-    ``chunks`` chunks of ``rows_per_chunk`` rows: the ladder 1, 2, 3, 4, 6,
-    8, 12, … up to the longest run, half (rounded up) of a task of every
-    chunk and at most :data:`RUN_MAX_ROWS` rows.  Runs of 3 or fewer
-    chunks keep their greedy length; a longer one is rounded down at most
-    by a third."""
-    longest = min((chunks + 1) // 2, max(RUN_MAX_ROWS // rows_per_chunk, 1))
+    ``chunks`` chunks: the ladder 1, 2, 3, 4, 6, 8, 12, … up to the
+    longest run, half (rounded up) of a task of every chunk.  Runs of 3 or
+    fewer chunks keep their greedy length; a longer one is rounded down at
+    most by a third."""
+    longest = (chunks + 1) // 2
     return [n for n in range(1, longest + 1) if _on_ladder(n)]
 
 
@@ -258,14 +248,13 @@ class KernelBackend:
       once; small operands are content-keyed, large immutable blocks are
       identity-keyed (content-keying an ``(d, B)`` block would cost
       O(d·B) per call);
-    * each call — one chunk, or a worker's run of contiguous chunks of at
-      most :data:`RUN_MAX_ROWS` rows — is one ``ops.chunk_matvec`` call on
-      the resident shard: the start row is a traced argument, so one
-      compiled program per ``(shard shape, rows of the call, B)`` serves
-      every call of that size (the run lengths of
-      :func:`run_lengths`: three programs at 328 rows a chunk, six at
-      25), and the rows are walked in blocks small enough for the
-      kernel's VMEM whatever their count.  Multi-RHS widths are bucketed
+    * each call — one chunk, or a worker's run of contiguous chunks — is
+      one ``ops.chunk_matvec`` call on the resident shard: the start row
+      is a traced argument, so one compiled program per ``(shard shape,
+      rows of the call, B)`` serves every call of that size (the run
+      lengths of :func:`run_lengths`: six programs at 20 chunks a shard),
+      and the rows are walked in blocks small enough for the kernel's
+      VMEM whatever their count.  Multi-RHS widths are bucketed
       to the next power of two (floor 1), so coalesced batch widths land
       on a handful of kernel shapes instead of retracing for every
       distinct ``B``.
@@ -697,9 +686,9 @@ class Worker(threading.Thread):
             self) -> Tuple[_TaskProgress, List[Tuple[int, int, int]]]:
         """Pop the head item and the queued items that one backend call
         can take with it (the caller holds ``_cv``): items of the head's
-        task whose rows continue the run's, up to :data:`RUN_MAX_ROWS` rows
-        and half, rounded up, of the task's queued chunks.  Only a
-        shard-aware backend takes more than the head."""
+        task whose rows continue the run's, up to half, rounded up, of the
+        task's queued chunks.  Only a shard-aware backend takes more than
+        the head."""
         # s2c2lint: ignore[S2C201] _locked-suffix contract: caller holds _cv
         items = self._items
         tp, chunk_id, r0, r1 = items.popleft()
@@ -711,7 +700,7 @@ class Worker(threading.Thread):
         cap = (tp.remaining + 1) // 2
         while len(run) < cap and items:
             nxt, chunk_id, n0, n1 = items[0]
-            if nxt is not tp or n0 != r1 or n1 - r0 > RUN_MAX_ROWS:
+            if nxt is not tp or n0 != r1:
                 break
             items.popleft()
             run.append((chunk_id, n0, n1))
@@ -734,7 +723,7 @@ class Worker(threading.Thread):
             return 0
         with self._shard_lock:
             a = self.shards[shard_id]
-        lengths = run_lengths(rows_per_chunk, chunks)
+        lengths = run_lengths(chunks)
         for n in lengths:
             self._compute_chunk(self.worker_id, shard_id, a, 0,
                                 n * rows_per_chunk, x)

@@ -21,13 +21,19 @@ from repro.kernels.lstm_cell import lstm_cell_pallas
 from repro.kernels.mds_decode import mds_decode_pallas
 from repro.kernels.mds_encode import mds_encode_pallas
 
-__all__ = ["coded_matvec", "chunk_matvec", "mds_encode", "mds_decode",
-           "lstm_cell", "interpret_default", "MAX_BLOCK_ROWS"]
+__all__ = ["coded_matvec", "chunk_matvec", "row_block", "mds_encode",
+           "mds_decode", "lstm_cell", "interpret_default", "MAX_BLOCK_ROWS",
+           "MIN_SPLIT_ROWS"]
 
 #: Largest row block ``chunk_matvec`` hands the kernel.  A (1024, 512) f32
 #: tile double-buffered plus its accumulator stays well inside the 16 MiB
 #: scoped VMEM of a v5e core; a 4096-row block does not.
 MAX_BLOCK_ROWS = 1024
+
+#: Smallest block a call of more than :data:`MAX_BLOCK_ROWS` rows is split
+#: into to leave no row padded, so that such a call is never walked in
+#: many small blocks, each a grid step of fixed cost.
+MIN_SPLIT_ROWS = 256
 
 
 def interpret_default() -> bool:
@@ -79,19 +85,36 @@ def coded_matvec(a: jax.Array, x: jax.Array, block_ids: jax.Array,
     return out[:, :, 0] if squeeze else out
 
 
+def row_block(rows: int) -> int:
+    """Rows of each block ``chunk_matvec`` walks a call of ``rows`` rows in.
+
+    Up to :data:`MAX_BLOCK_ROWS` rows the call is one block, rounded up to
+    8 rows.  A longer call takes the largest multiple of 8 that divides
+    ``rows`` and lies between :data:`MIN_SPLIT_ROWS` and
+    :data:`MAX_BLOCK_ROWS`, so that no row is padded; only where none does
+    is it :data:`MAX_BLOCK_ROWS`, the last block padded.
+    """
+    if rows <= MAX_BLOCK_ROWS:
+        return _round_up(rows, 8)
+    for block in range(MAX_BLOCK_ROWS, MIN_SPLIT_ROWS - 1, -8):
+        if rows % block == 0:
+            return block
+    return MAX_BLOCK_ROWS
+
+
 @functools.partial(jax.jit, static_argnames=("rows", "interpret"))
 def chunk_matvec(shard: jax.Array, x: jax.Array, r0: jax.Array, rows: int,
                  interpret: bool | None = None) -> jax.Array:
     """``shard[r0:r0+rows] @ x`` for one engine chunk, in one kernel call.
 
-    The chunk is walked as blocks of at most :data:`MAX_BLOCK_ROWS` rows
-    through the kernel's block-id table (rows padded to a whole number of
-    blocks), so any chunk size fits the kernel's VMEM.  ``r0`` is traced:
-    one compiled program serves every chunk of a shard; ``r0 + rows`` must
-    not exceed the shard's rows.  Returns ``(rows,)`` for a vector ``x``,
-    else ``(rows, nvec)``.
+    The chunk is walked as blocks of :func:`row_block` rows through the
+    kernel's block-id table (rows padded to a whole number of blocks), so
+    any chunk size fits the kernel's VMEM.  ``r0`` is traced: one compiled
+    program serves every chunk of a shard; ``r0 + rows`` must not exceed
+    the shard's rows.  Returns ``(rows,)`` for a vector ``x``, else
+    ``(rows, nvec)``.
     """
-    block = min(_round_up(rows, 8), MAX_BLOCK_ROWS)
+    block = row_block(rows)
     padded = _round_up(rows, block)
     a = jax.lax.dynamic_slice_in_dim(shard, r0, rows)
     a = jnp.pad(a, ((0, padded - rows), (0, 0)))

@@ -2,9 +2,9 @@
 
 A :class:`~repro.cluster.worker.Worker` with a shard-aware backend hands
 it the head of its queue together with the queued chunks of the same task
-whose rows continue it — at most ``RUN_MAX_ROWS`` rows and half, rounded
-up, of the task's queued chunks, rounded down to the ladder 1, 2, 3, 4,
-6, 8, … that ``Worker.warm_runs`` compiles ahead — and splits the call's
+whose rows continue it — at most half, rounded up, of the task's queued
+chunks, rounded down to the ladder 1, 2, 3, 4, 6, 8, … that
+``Worker.warm_runs`` compiles ahead — and splits the call's
 result back into one :class:`ChunkDone` per chunk.  A plain ``compute(a_rows, x)`` backend
 keeps one call per chunk.
 """
@@ -18,8 +18,8 @@ import pytest
 from repro.cluster import (ClusterConfig, CodedExecutionEngine, NoSlowdown,
                            Worker, kernel_backend)
 from repro.cluster import obs
-from repro.cluster.worker import (RUN_MAX_ROWS, ChunkDone, ChunkTask,
-                                  WorkerDone, WorkerFailed, run_lengths)
+from repro.cluster.worker import (ChunkDone, ChunkTask, WorkerDone,
+                                  WorkerFailed, run_lengths)
 from repro.core.strategies import GeneralS2C2
 
 RNG = np.random.default_rng(15)
@@ -93,11 +93,6 @@ def _run(compute, tasks, shard_rows, cols=4, shard=None):
     return evs, shard
 
 
-def test_run_limit_is_the_kernels_block():
-    from repro.kernels import ops
-    assert RUN_MAX_ROWS == ops.MAX_BLOCK_ROWS
-
-
 class TestRunBoundaries:
     def test_one_call_for_a_contiguous_run(self):
         rec = _Recorder()
@@ -140,16 +135,18 @@ class TestRunBoundaries:
             [3, 0, 1, 2]
 
     @pytest.mark.parametrize("rows,want", [
-        (400, [2, 2, 1, 1]),          # a third would pass RUN_MAX_ROWS
-        (512, [2, 2, 1, 1]),          # two fill one block exactly
-        (513, [1] * 6),               # over half a block: one per call
-        (328, [3, 2, 1])])            # the benchmark's chunk
-    def test_run_holds_at_most_the_kernels_block(self, rows, want):
+        (400, [3, 2, 1]),             # a first call of 1200 rows
+        (512, [3, 2, 1]),
+        (513, [3, 2, 1]),             # over half a kernel block: runs too
+        (328, [8, 6, 3, 2, 1])])      # the benchmark's 20 chunks a shard
+    def test_run_rows_are_bounded_by_the_half_rule_alone(self, rows, want):
+        """However many rows a chunk holds, a run takes the half of the
+        task's queue the ladder allows: calls of more than 1024 rows."""
         rec = _Recorder()
         n = sum(want)
         _run(rec, [_task(_even(n, rows), cols=2)], n * rows, cols=2)
         assert [(r1 - r0) // rows for r0, r1 in rec.calls] == want
-        assert max(r1 - r0 for r0, r1 in rec.calls) <= RUN_MAX_ROWS
+        assert rec.calls[0][1] - rec.calls[0][0] > 1024
 
     def test_numpy_backend_keeps_one_call_per_chunk(self):
         seen = []
@@ -189,6 +186,33 @@ class TestKernelRuns:
             assert e.result.shape == alone.shape
             assert e.result.dtype == np.float64
             np.testing.assert_array_equal(e.result, alone)
+
+    @pytest.mark.parametrize("rows", [328, 480])
+    def test_a_run_of_more_than_1024_rows_is_one_call(self, rows):
+        """Through KernelBackend, a run of 4 chunks of the benchmark's
+        sizes is one call of 1312 or 1920 rows (blocks of 656 or 960), and
+        each chunk's slice of it is bit for bit the chunk's own call."""
+        n, cols = 8, 128
+        shard = RNG.standard_normal((rows * n, cols))
+        x = RNG.standard_normal(cols)
+        x.flags.writeable = False
+        task = _task(_even(n, rows), x=x)
+        be = kernel_backend()
+        calls = []
+        compute_chunk = be.compute_chunk
+
+        def spy(worker_id, shard_id, a, r0, r1, x_):
+            calls.append((r0, r1))
+            return compute_chunk(worker_id, shard_id, a, r0, r1, x_)
+
+        be.compute_chunk = spy
+        evs, _ = _run(be, [task], rows * n, shard=shard)
+        assert [(r1 - r0) // rows for r0, r1 in calls] == [4, 2, 1, 1]
+        done = [e for e in evs if isinstance(e, ChunkDone)]
+        assert [e.chunk_id for e in done] == list(range(n))
+        for e, (c, r0, r1) in zip(done, task.chunks):
+            np.testing.assert_array_equal(
+                e.result, compute_chunk(0, "s", shard, r0, r1, x))
 
     def test_tracer_gets_a_chunk_record_per_chunk_of_a_run(self):
         tr = obs.Tracer()
@@ -340,14 +364,16 @@ class TestRunSemantics:
             eng.shutdown()
 
 
-@pytest.mark.parametrize("rows,chunks,want", [
-    (328, 20, [1, 2, 3]),               # 65536 x 8192 on (12, 10): as before
-    (480, 20, [1, 2]),
-    (25, 20, [1, 2, 3, 4, 6, 8]),       # the transpose of 96000 x 5000
-    (2000, 20, [1]),
-    (8, 40, [1, 2, 3, 4, 6, 8, 12, 16])])
-def test_run_lengths_are_the_ladder(rows, chunks, want):
-    assert run_lengths(rows, chunks) == want
+@pytest.mark.parametrize("chunks,want", [
+    # 20 chunks a shard: 328 rows (65536 x 8192 on (12, 10)), 480 rows
+    # (96000 x 5000), 25 rows (its transpose) and 2000 rows alike
+    (20, [1, 2, 3, 4, 6, 8]),
+    (17, [1, 2, 3, 4, 6, 8]),
+    (5, [1, 2, 3]),
+    (1, [1]),
+    (40, [1, 2, 3, 4, 6, 8, 12, 16])])
+def test_run_lengths_are_the_ladder(chunks, want):
+    assert run_lengths(chunks) == want
 
 
 def test_warm_runs_makes_every_call_a_round_can():
@@ -357,7 +383,7 @@ def test_warm_runs_makes_every_call_a_round_can():
     w = Worker(0, queue.Queue(), NoSlowdown(), compute=rec)
     w.install_shard("s", RNG.standard_normal((17 * 8, 4)))
     x = RNG.standard_normal(4)
-    lengths = run_lengths(8, 17)
+    lengths = run_lengths(17)
     assert w.warm_runs("s", 8, 17, x) == len(lengths)
     assert rec.calls == [(0, 8 * n) for n in lengths]
     warmed = {r1 - r0 for r0, r1 in rec.calls}

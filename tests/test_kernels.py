@@ -80,6 +80,9 @@ class TestChunkMatvec:
         (96, 13, 5, 3),                           # unaligned start, tiny
         (2 * ops.MAX_BLOCK_ROWS + 300, 150,       # three blocks, the last
          2 * ops.MAX_BLOCK_ROWS + 100, 2),        # one mostly padding
+        # a run of 8 chunks from chunk 2: blocks of 656 and 960, no padding
+        (10 * 328, 2 * 328, 8 * 328, None),
+        (10 * 480, 2 * 480, 8 * 480, 3),
     ])
     def test_matches_slice_product(self, shard_rows, r0, rows, nvec):
         d = 128
@@ -91,6 +94,20 @@ class TestChunkMatvec:
         assert got.shape == want.shape
         np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4,
                                    atol=2e-4)
+
+    @pytest.mark.parametrize("chunk_rows", [328, 480])
+    def test_runs_are_walked_in_blocks_with_no_padded_rows(self, chunk_rows):
+        """Every run length of 20 chunks a shard divides into whole blocks
+        of at least MIN_SPLIT_ROWS rows; up to MAX_BLOCK_ROWS a call is
+        one block of its rows rounded up to 8, as it always was."""
+        from repro.cluster.worker import run_lengths
+        for n in run_lengths(20):
+            rows = n * chunk_rows
+            block = ops.row_block(rows)
+            assert rows % block == 0 and block % 8 == 0
+            assert ops.MIN_SPLIT_ROWS <= block <= ops.MAX_BLOCK_ROWS
+        for rows in range(1, ops.MAX_BLOCK_ROWS + 1):
+            assert ops.row_block(rows) == -(-rows // 8) * 8
 
     def test_start_row_is_traced(self):
         """Every chunk of a shard reuses one compiled program."""

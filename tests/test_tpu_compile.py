@@ -76,6 +76,9 @@ def _compiled_text(fn, *args) -> str:
     (SHARD_ROWS, 3 * CHUNK_ROWS, None, jnp.float32),
     (SHARD_ROWS, 2 * CHUNK_ROWS, 16, jnp.float32),
     (SHARD_ROWS, 3 * CHUNK_ROWS, 16, jnp.float32),
+    # a run of eight: 2624 rows in four blocks of 656
+    (SHARD_ROWS, 8 * CHUNK_ROWS, None, jnp.float32),
+    (SHARD_ROWS, 8 * CHUNK_ROWS, 16, jnp.float32),
 ])
 def test_chunk_matvec(one_chip, shard_rows, rows, nvec, dtype):
     x_shape = (D,) if nvec is None else (D, nvec)
