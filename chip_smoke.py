@@ -18,14 +18,9 @@ Phases (one process, one chip):
 2. One round on a second engine with ``decode_with_kernel=True``, so the
    Pallas ``mds_decode`` runs on the chip too (after its own warm-up).
 
-``--four-chips`` runs only the multi-chip path instead: the ``shard_map``
-coded matvec of ``repro.core.coded_matmul`` over a 4-device ``workers``
-mesh, (n, k) = (4, 3), A of 49152 × 8192, under several allocations with
-one straggler each.
-
 Run from the checkout root on a machine with a TPU::
 
-    python chip_smoke.py [--seed N] [--four-chips]
+    python chip_smoke.py [--seed N]
 
 Without a TPU it exits non-zero before doing any work.  The last line of
 standard output is one JSON object, ``{"ok": true, "device": {...}}``,
@@ -53,8 +48,6 @@ ROWS, COLS = 65536, 8192                 # the single-chip matrix
 N, K, CHUNKS, STRAGGLERS = 12, 10, 20, 2
 B1_VECTORS, BATCH = 4, 16
 MIN_SHARD_BYTES = 2 * 10**9              # device-resident shards, at least
-
-FOUR_ROWS, FOUR_N, FOUR_K, FOUR_CHUNKS = 49152, 4, 3, 16
 
 
 def log(msg: str) -> None:
@@ -240,62 +233,9 @@ def _phase_kernel_decode(a, xs, ref, cfg, injector, check: Checks,
         eng.shutdown()
 
 
-def four_chips(rows: int, cols: int, seed: int, check: Checks) -> None:
-    """The shard_map coded matvec over a 4-device ``workers`` mesh."""
-    import jax
-    import jax.numpy as jnp
-    from repro.core.coded_matmul import CodedMatvec
-    from repro.core.coding import MDSCode
-    from repro.core.s2c2 import general_allocation
-    from repro.launch.mesh import make_worker_mesh
-
-    devs = jax.devices()
-    check(len(devs) == FOUR_N, f"{len(devs)} devices == {FOUR_N}")
-    if len(devs) != FOUR_N:
-        return
-    rng = np.random.default_rng(seed)
-    a64 = rng.standard_normal((rows, cols))
-    xs = rng.standard_normal((3, cols))
-    ref = xs @ a64.T
-    cm = CodedMatvec(MDSCode(FOUR_N, FOUR_K), chunks=FOUR_CHUNKS,
-                     mesh=make_worker_mesh(FOUR_N))
-    t0 = time.perf_counter()
-    coded = cm.shard(jnp.asarray(a64, jnp.float32))
-    coded.block_until_ready()
-    log(f"setup: encode + shard {time.perf_counter() - t0:.3f}s, "
-        f"coded {coded.shape} {coded.dtype}")
-    shards = coded.addressable_shards
-    held = sorted(s.device.id for s in shards)
-    check(held == sorted(d.id for d in devs) and all(
-        s.data.shape[0] == 1 for s in shards),
-        "one partition per device: "
-        f"{[(s.device.id, s.data.shape) for s in shards]}")
-    for d in devs:
-        log(memory_line(d))
-
-    apply = cm.jit_apply()
-    allocations = ([1.0, 1.0, 1.0, 1.0], [1.0, 1.0, 1.0, 0.2],
-                   [0.2, 1.0, 1.0, 1.0], [1.0, 0.2, 1.0, 1.0])
-    for step, speeds in enumerate(allocations):
-        x = jnp.asarray(xs[step % len(xs)], jnp.float32)
-        tables = cm.plan_tables(general_allocation(np.asarray(speeds),
-                                                   FOUR_K, FOUR_CHUNKS))
-        t0 = time.perf_counter()
-        y = apply(coded, x, *tables)
-        y.block_until_ready()
-        secs = time.perf_counter() - t0
-        err = rel_err(np.asarray(y, np.float64)[:rows], ref[step % len(xs)])
-        log(f"allocation speeds={speeds} {secs:.3f}s"
-            f"{' (includes compile)' if step == 0 else ''}")
-        check(err <= TOL, f"four-chip speeds={speeds}: max rel err "
-                          f"{err!r} <= {TOL}")
-
-
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--four-chips", action="store_true",
-                    help="run only the 4-chip shard_map phase")
     args = ap.parse_args()
 
     import jax
@@ -311,13 +251,8 @@ def main() -> int:
 
     check = Checks()
     t0 = time.perf_counter()
-    if args.four_chips:
-        four_chips(FOUR_ROWS, COLS, args.seed, check)
-        used = FOUR_N
-    else:
-        single_chip(ROWS, COLS, args.seed, row_cost=1e-5, check=check,
-                    min_shard_bytes=MIN_SHARD_BYTES)
-        used = 1
+    single_chip(ROWS, COLS, args.seed, row_cost=1e-5, check=check,
+                min_shard_bytes=MIN_SHARD_BYTES)
     log(f"total {time.perf_counter() - t0:.3f}s")
     if check.failed:
         print(f"chip_smoke: {len(check.failed)} check(s) failed",
@@ -325,7 +260,7 @@ def main() -> int:
         return 1
     print(json.dumps({"ok": True, "device": {
         "platform": devs[0].platform, "kind": devs[0].device_kind,
-        "count": used}}))
+        "count": 1}}))
     return 0
 
 

@@ -63,8 +63,6 @@ class ArchConfig:
     remat: bool = True
     tie_embeddings: bool = False
 
-    # dry-run tuning (per-shape grad accumulation chosen in launch/steps.py)
-    grad_accum_train: int = 8
     # sequence-parallel activations at scan boundaries (SP): shards the
     # saved layer-boundary activations over the model axis — required to
     # fit deep/wide archs' remat carries in HBM (see DESIGN.md §5)
@@ -150,7 +148,6 @@ class ArchConfig:
             # both attention paths within its 4 layers
             local_global_ratio=1 if self.attn_pattern == "local_global"
             else self.local_global_ratio,
-            grad_accum_train=1,
             dtype="float32",
         )
 

@@ -19,6 +19,5 @@ CONFIG = ArchConfig(
     attn_pattern="global",
     mlp_type="swiglu",
     optimizer="adamw",
-    grad_accum_train=16,
     seq_shard_train=True,
 )

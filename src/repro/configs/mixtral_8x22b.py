@@ -23,6 +23,5 @@ CONFIG = ArchConfig(
     num_experts=8,
     experts_per_token=2,
     optimizer="adamw",
-    grad_accum_train=16,
     seq_shard_train=True,
 )

@@ -21,6 +21,5 @@ CONFIG = ArchConfig(
     mlp_type="squared_relu",
     norm_type="layernorm",
     optimizer="adafactor",
-    grad_accum_train=16,
     seq_shard_train=True,
 )

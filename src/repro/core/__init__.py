@@ -9,7 +9,6 @@ Public surface:
 * :mod:`repro.core.simulation` — trace-driven latency simulator.
 * :mod:`repro.core.strategies` — uncoded/MDS/over-decomp/S²C² strategies.
 * :mod:`repro.core.polynomial` — polynomial codes + S²C² on top (§5).
-* :mod:`repro.core.coded_matmul` — shard_map distributed coded matvec.
 * :mod:`repro.core.gradient_coding` — DP-level gradient coding (beyond-linear).
 """
 

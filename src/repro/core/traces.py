@@ -14,8 +14,8 @@ shared droplets:
 * small iid measurement noise.
 
 Also provides deterministic *controlled-cluster* scenarios (exact straggler
-counts) used by the Fig. 1/6/7 benchmarks, mirroring the paper's local
-cluster where straggler behavior was precisely controlled.
+counts) used by the smoke run, the examples and the tests, mirroring the
+paper's local cluster where straggler behavior was precisely controlled.
 """
 
 from __future__ import annotations

@@ -1,6 +1,6 @@
 """Where JAX keeps its persistent compilation cache for this checkout.
 
-Entry points (``chip_smoke.py``, ``benchmarks/run.py``) call
+Entry points (``chip_smoke.py``, ``bench/run.py``) call
 :func:`use_compile_cache` once, before their first compile.  Library
 modules never call it when imported, and tests never call it.
 """

@@ -1,7 +1,7 @@
 """Low-level logical-axis partitioning helpers (no model imports).
 
-Split out of launch/sharding.py so model code can use ``constrain`` without
-a circular import (models → partition ← sharding → models.params).
+Kept free of model imports so model code can use ``constrain`` without a
+circular import.
 """
 
 from __future__ import annotations

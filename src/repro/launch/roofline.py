@@ -1,4 +1,4 @@
-"""Roofline analysis from compiled dry-run artifacts.
+"""Roofline analysis from compiled HLO.
 
 Terms per (arch × shape × mesh), all per-chip, in seconds:
 

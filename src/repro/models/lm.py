@@ -16,7 +16,7 @@ Paths:
                      caches (python-unrolled: cache shapes may differ per
                      layer — rotating windows vs full, SSM states).
 * ``decode_step``  — single-token step over unrolled layers with explicit
-                     cache I/O (the ``serve_step`` the dry-run lowers).
+                     cache I/O.
 """
 
 from __future__ import annotations

@@ -5,11 +5,10 @@ Models declare their parameters as a nested dict of :class:`ParamSpec`
 
 * ``abstract(specs)``   — ShapeDtypeStructs for allocation-free dry-runs;
 * ``initialize(specs)`` — real arrays for smoke tests / training;
-* ``logical_axes(specs)`` — the axes tree consumed by
-  :mod:`repro.launch.sharding` to produce NamedShardings via a rules table
-  (t5x-style logical→mesh mapping).
+* ``logical_axes(specs)`` — the axes tree, mapped to mesh axes by a rules
+  table (t5x-style; :data:`repro.launch.partition.DEFAULT_RULES`).
 
-Logical axis vocabulary (see launch/sharding.py for the mesh rules):
+Logical axis vocabulary:
 ``layers, vocab, embed, q_proj, kv_proj, heads, head_dim, mlp, expert,
 conv, state, unsharded``.
 """

@@ -1,9 +1,5 @@
-"""Runtime integration: coded DP training (faults, timeout, restart),
-serving (coded lm_head), distributed coded matvec via shard_map."""
-
-import subprocess
-import sys
-import textwrap
+"""Runtime integration: coded DP training (faults, timeout, restart) and
+the failure detector."""
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +12,6 @@ from repro.data.pipeline import TokenPipeline
 from repro.models import build_model
 from repro.models.params import initialize
 from repro.optim.optimizer import make_optimizer
-from repro.runtime.serve_loop import CodedLMHead, Request, ServeConfig, serve
 from repro.runtime.train_loop import CodedDPStep, TrainLoopConfig, train
 
 
@@ -103,28 +98,6 @@ class TestTrainLoopE2E:
         assert np.isfinite(m2["final_loss"])
 
 
-class TestCodedLMHead:
-    def test_logits_exact_any_speeds(self):
-        rng = np.random.default_rng(0)
-        d, v = 32, 96
-        head = jnp.asarray(rng.standard_normal((d, v)), jnp.float32)
-        x = jnp.asarray(rng.standard_normal((3, d)), jnp.float32)
-        ch = CodedLMHead(head, n=6, k=4, chunks=8)
-        want = np.asarray(x @ head)
-        for speeds in (np.ones(6), np.array([1, 1, 1, 1, 0.1, 0.1]),
-                       np.array([2.0, 1, 1, 0.5, 1, 1])):
-            got = np.asarray(ch.logits(x, speeds))
-            np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
-
-    def test_serve_greedy(self):
-        cfg, model, params = _tiny_setup()
-        reqs = [Request(rid=i, prompt=np.arange(1, 5, dtype=np.int32),
-                        max_new=3) for i in range(2)]
-        out = serve(model, params, reqs, ServeConfig(max_batch=2))
-        assert set(out) == {0, 1}
-        assert all(len(v) == 3 for v in out.values())
-
-
 class TestFailureDetector:
     def test_straggler_flagged_with_full_responses(self):
         from repro.runtime.elastic import FailureDetector
@@ -164,39 +137,3 @@ class TestFailureDetector:
         out = det.evaluate(rt)
         assert out["dead"] == {4}
 
-
-class TestDistributedCodedMatvec:
-    def test_shard_map_path(self):
-        """Full distributed path on 4 virtual devices (subprocess so the
-        XLA device-count flag doesn't leak into this test process)."""
-        prog = textwrap.dedent("""
-            import os
-            os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-            import numpy as np, jax, jax.numpy as jnp
-            from repro.core.coding import MDSCode
-            from repro.core.coded_matmul import CodedMatvec
-            from repro.core.s2c2 import general_allocation
-            from repro.launch.mesh import make_worker_mesh
-            code = MDSCode(n=4, k=3)
-            mesh = make_worker_mesh(4)
-            cm = CodedMatvec(code, chunks=6, mesh=mesh)
-            rng = np.random.default_rng(0)
-            a = jnp.asarray(rng.standard_normal((90, 16)), jnp.float32)
-            x = jnp.asarray(rng.standard_normal((16,)), jnp.float32)
-            coded = cm.shard(a)
-            for speeds in ([1,1,1,1], [1,1,1,0.2], [2,1,1,1]):
-                alloc = general_allocation(speeds, 3, 6)
-                b, c, w = cm.plan_tables(alloc)
-                y = cm.apply(coded, x, b, c, w)
-                want = np.asarray(a @ x)
-                got = np.asarray(y)[: want.shape[0]]
-                assert np.allclose(got, want, rtol=3e-3, atol=3e-3), speeds
-            print("DISTRIBUTED_OK")
-        """)
-        r = subprocess.run([sys.executable, "-c", prog], capture_output=True,
-                           text=True, env={**__import__('os').environ,
-                                           "PYTHONPATH": "src"},
-                           cwd=__import__('os').path.dirname(
-                               __import__('os').path.dirname(__file__)),
-                           timeout=300)
-        assert "DISTRIBUTED_OK" in r.stdout, r.stderr[-2000:]

@@ -5,8 +5,10 @@ described, and refuses what the chip would refuse (misaligned blocks, more
 VMEM than a kernel may use).  Interpret mode on the CPU cannot see either.
 Shapes are those of ``chip_smoke.py``: a 65536 × 8192 matrix encoded at
 (n, k) = (12, 10) with 20 chunks, so each worker shard is 6560 × 8192 and
-each chunk 328 rows; its ``--four-chips`` phase is compiled over the
-described chip's 2x2 mesh.
+each chunk 328 rows.  The chunk matvec is also compiled at the shapes of
+the gradient-descent cell: A of 96000 × 5000 and its transpose at the
+same code, so shards of 9600 × 5000 (chunks of 480 rows) and 500 × 96000
+(chunks of 25 rows).
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
@@ -18,7 +20,6 @@ from __future__ import annotations
 import functools
 import os
 
-import numpy as np
 import pytest
 
 import jax
@@ -66,24 +67,38 @@ def _compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("shard_rows,rows,nvec,dtype", [
-    (SHARD_ROWS, CHUNK_ROWS, None, jnp.float32),   # the smoke's B=1 chunk
-    (SHARD_ROWS, CHUNK_ROWS, 16, jnp.float32),     # the smoke's B=16 chunk
-    (8192, 8192, None, jnp.float32),               # one 8192-row chunk
-    (SHARD_ROWS, CHUNK_ROWS, 16, jnp.bfloat16),    # bf16: default precision
+def _case(shard_rows, rows, nvec, dtype, width=D):
+    """One ``test_chunk_matvec`` case; its id names the shard's width only
+    where it is not the smoke's."""
+    shard = shard_rows if width == D else f"{shard_rows}x{width}"
+    return pytest.param(shard_rows, width, rows, nvec, dtype,
+                        id=f"{shard}-{rows}-{nvec}-{dtype.__name__}")
+
+
+@pytest.mark.parametrize("shard_rows,width,rows,nvec,dtype", [
+    _case(SHARD_ROWS, CHUNK_ROWS, None, jnp.float32),  # the smoke's B=1 chunk
+    _case(SHARD_ROWS, CHUNK_ROWS, 16, jnp.float32),    # the smoke's B=16 chunk
+    _case(8192, 8192, None, jnp.float32),              # one 8192-row chunk
+    _case(SHARD_ROWS, CHUNK_ROWS, 16, jnp.bfloat16),   # bf16: default precision
     # a worker's run of two or three contiguous chunks in one call
-    (SHARD_ROWS, 2 * CHUNK_ROWS, None, jnp.float32),
-    (SHARD_ROWS, 3 * CHUNK_ROWS, None, jnp.float32),
-    (SHARD_ROWS, 2 * CHUNK_ROWS, 16, jnp.float32),
-    (SHARD_ROWS, 3 * CHUNK_ROWS, 16, jnp.float32),
+    _case(SHARD_ROWS, 2 * CHUNK_ROWS, None, jnp.float32),
+    _case(SHARD_ROWS, 3 * CHUNK_ROWS, None, jnp.float32),
+    _case(SHARD_ROWS, 2 * CHUNK_ROWS, 16, jnp.float32),
+    _case(SHARD_ROWS, 3 * CHUNK_ROWS, 16, jnp.float32),
     # a run of eight: 2624 rows in four blocks of 656
-    (SHARD_ROWS, 8 * CHUNK_ROWS, None, jnp.float32),
-    (SHARD_ROWS, 8 * CHUNK_ROWS, 16, jnp.float32),
+    _case(SHARD_ROWS, 8 * CHUNK_ROWS, None, jnp.float32),
+    _case(SHARD_ROWS, 8 * CHUNK_ROWS, 16, jnp.float32),
+    # gradient descent, A·w: runs of eight and of six 480-row chunks
+    _case(9600, 8 * 480, None, jnp.float32, width=5000),
+    _case(9600, 6 * 480, None, jnp.float32, width=5000),
+    # gradient descent, Aᵀ·r: a run of eight 25-row chunks, and one chunk
+    _case(500, 8 * 25, None, jnp.float32, width=96000),
+    _case(500, 25, None, jnp.float32, width=96000),
 ])
-def test_chunk_matvec(one_chip, shard_rows, rows, nvec, dtype):
-    x_shape = (D,) if nvec is None else (D, nvec)
+def test_chunk_matvec(one_chip, shard_rows, width, rows, nvec, dtype):
+    x_shape = (width,) if nvec is None else (width, nvec)
     fn = functools.partial(ops.chunk_matvec, rows=rows, interpret=False)
-    text = _compiled_text(fn, _spec((shard_rows, D), one_chip, dtype),
+    text = _compiled_text(fn, _spec((shard_rows, width), one_chip, dtype),
                           _spec(x_shape, one_chip, dtype),
                           _spec((), one_chip, jnp.int32))
     assert "tpu_custom_call" in text
@@ -104,25 +119,6 @@ def test_mds_encode(one_chip):
     text = _compiled_text(fn, _spec((N, K), one_chip),
                           _spec((K, SHARD_ROWS, D), one_chip))
     assert "tpu_custom_call" in text
-
-
-def test_four_chip_coded_matvec(topo):
-    """``chip_smoke.py --four-chips``: the shard_map matvec over a 2x2 mesh,
-    (n, k) = (4, 3), 16 chunks, A of 49152 × 8192 (a psum decodes)."""
-    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
-    from repro.core.coded_matmul import CodedMatvec
-    from repro.core.coding import MDSCode
-    mesh = Mesh(np.array(topo.devices), ("workers",),
-                axis_types=(AxisType.Auto,))
-    cm = CodedMatvec(MDSCode(4, 3), chunks=16, mesh=mesh)
-    rep = NamedSharding(mesh, P())
-    args = (_spec((4, 16384, D), NamedSharding(mesh, P("workers"))),
-            _spec((D,), rep), _spec((4,), rep, jnp.int32),
-            _spec((4,), rep, jnp.int32), _spec((16, 3, 4), rep))
-    compiled = jax.jit(cm.apply).lower(*args).compile()
-    assert "all-reduce" in compiled.as_text()
-    # each chip holds one 16384 × 8192 float32 partition
-    assert compiled.memory_analysis().argument_size_in_bytes < 16384 * D * 5
 
 
 def test_lstm_cell(one_chip):
